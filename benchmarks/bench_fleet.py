@@ -10,17 +10,12 @@ cap-event-dense replicas whose quiet horizons collapse to tens of ticks
 while everyone else fast-forwards — where the vmapped while-loop pays the
 busy replicas' trip count for every lane and sharding confines it to one
 device. Every sharded row carries a ``match_vmapped`` derived field
-(bitwise final-state equality, asserted). When the current process has
-fewer than 2 devices the bench re-execs itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (device count is
-locked at first jax init, same trick as tests/test_multidevice.py)."""
+(bitwise final-state equality, asserted). It needs at least 2 devices in
+the calling process and raises otherwise: a child process would contend
+with its parent for an accelerator."""
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 from typing import List, Tuple
 
@@ -96,8 +91,17 @@ def bench_fleet() -> List[Row]:
     return rows
 
 
-def _sharded_rows(smoke: bool = False) -> List[Row]:
-    """Body of ``bench_fleet_sharded``; needs >=2 jax devices."""
+def bench_fleet_sharded(smoke: bool = False) -> List[Row]:
+    """Sharded-vs-vmapped fleet rows; needs >= 2 jax devices in this
+    process (on a CPU host, set
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax
+    starts, as the CI multidevice job does)."""
+    n = len(jax.devices())
+    if n < 2:
+        raise RuntimeError(
+            f"bench_fleet_sharded needs >= 2 devices, this process has {n}; "
+            "on a CPU host force host devices with "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=8")
     import numpy as np
 
     from repro.configs.sim import tiny_cluster
@@ -183,31 +187,3 @@ def _sharded_rows(smoke: bool = False) -> List[Row]:
             f"speedup_vs_vmapped={dt_v/dt_s:.2f}x;match_vmapped={ok}",
         ))
     return rows
-
-
-def bench_fleet_sharded(smoke: bool = False) -> List[Row]:
-    if len(jax.devices()) >= 2:
-        return _sharded_rows(smoke)
-    # device count is locked at first jax init — re-exec with forced host
-    # devices and relay the rows (same pattern as tests/test_multidevice)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        " --xla_force_host_platform_device_count=8").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(root, "src"), env.get("PYTHONPATH")) if p)
-    r = subprocess.run(
-        [sys.executable, os.path.abspath(__file__)]
-        + (["--smoke"] if smoke else []),
-        capture_output=True, text=True, env=env, timeout=1800)
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"sharded fleet sub-bench failed\nSTDOUT:\n{r.stdout}\n"
-            f"STDERR:\n{r.stderr}")
-    payload = json.loads(r.stdout.strip().splitlines()[-1])
-    return [tuple(row) for row in payload]
-
-
-if __name__ == "__main__":
-    # subprocess entry for bench_fleet_sharded: emit rows as one JSON line
-    print(json.dumps(_sharded_rows(smoke="--smoke" in sys.argv[1:])))

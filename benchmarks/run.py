@@ -245,8 +245,8 @@ def _run_bench_guarded(bench, timeout_s: float):
     """Run one bench on a daemon worker thread. Returns
     (result_rows | None, exception | None, timed_out). On timeout the
     worker keeps running detached (XLA compiles are not interruptible
-    from Python) — the harness moves on and records the row as timed
-    out instead of hanging the whole suite."""
+    from Python), so the caller must end the run: a next bench would
+    share the device with it."""
     out = {"rows": None, "exc": None}
 
     def work():
@@ -277,9 +277,9 @@ def main(argv=None) -> None:
                          "names (e.g. --only policy_grid,dispatch)")
     ap.add_argument("--timeout", type=float, default=0.0,
                     help="per-bench wall-clock budget in seconds (0 = none); "
-                         "a bench over budget gets one retry, then its row "
-                         "is recorded with timed_out=true and the suite "
-                         "moves on")
+                         "a bench over budget is recorded with "
+                         "timed_out=true and ends the run with a non-zero "
+                         "exit")
     ap.add_argument("--compare", nargs=2, metavar=("A.json", "B.json"),
                     default=None,
                     help="diff two BENCH artifacts row-by-row instead of "
@@ -304,18 +304,19 @@ def main(argv=None) -> None:
 
     print("name,us_per_call,derived")
     rows, failed = [], []
+    timed_out = False
     for bench in benches:
         bench_name = getattr(bench, "__name__", repr(bench))
         # transient failures (thread-pool races, flaky first compile) get
-        # ONE retry with a short backoff; a second strike is recorded
+        # ONE retry with a short backoff; a second strike is recorded. A
+        # timeout gets none: its thread still holds the device
         retries = 0
         while True:
             result, exc, timed_out = _run_bench_guarded(bench, args.timeout)
-            if result is not None or retries >= 1:
+            if result is not None or timed_out or retries >= 1:
                 break
             retries += 1
-            what = "timed out" if timed_out else f"failed ({exc!r})"
-            print(f"# {bench_name} {what}; retrying once in "
+            print(f"# {bench_name} failed ({exc!r}); retrying once in "
                   f"{RETRY_BACKOFF_S:.0f}s", file=sys.stderr, flush=True)
             time.sleep(RETRY_BACKOFF_S)
         if result is not None:
@@ -335,6 +336,8 @@ def main(argv=None) -> None:
             rows.append(
                 {"name": bench_name, "us_per_call": None, "derived": detail,
                  "retries": retries, "timed_out": bool(timed_out)})
+            if timed_out:
+                break
 
     # smoke numbers (tiny configs) and --only subsets must not claim a
     # numbered BENCH_<n> trajectory slot by default: numbered artifacts are
@@ -360,9 +363,15 @@ def main(argv=None) -> None:
             "rows": rows,
         }, f, indent=1)
     print(f"# perf artifact -> {out}", file=sys.stderr)
+    if timed_out:
+        raise SystemExit(f"{failed[-1]} timed out after {args.timeout:.0f}s; "
+                         "run stopped (its thread still holds the device)")
     if failed:
         raise SystemExit(f"benches failed: {failed}")
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -14,6 +14,7 @@ import jax
 from repro.configs.sim import tx_gaia
 from repro.core import build_statics, init_state, load_jobs, run_episode, summary
 from repro.data import synth_workload
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -40,4 +41,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

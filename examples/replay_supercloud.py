@@ -20,6 +20,7 @@ import jax
 from repro.configs.sim import tx_gaia
 from repro.core import build_statics, init_state, load_jobs, run_episode, summary
 from repro.data import load_supercloud, write_supercloud_csvs
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -47,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -22,6 +22,7 @@ from repro.core import build_statics, init_state, load_jobs, run_episode, summar
 from repro.data import synth_workload
 from repro.envs import SchedEnv
 from repro.rl import ActorCritic, PPOConfig, ppo_train
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def evaluate_policy(env, policy, params, key, episodes=4):
@@ -91,4 +92,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

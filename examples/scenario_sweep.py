@@ -36,6 +36,7 @@ from repro.scenarios import (
     heatwave,
     solar_heavy,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def build_scenarios(cfg, horizon_s):
@@ -101,4 +102,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.launch import train as train_mod
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -38,4 +39,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -20,6 +20,7 @@ import jax
 from repro.configs.sim import tx_gaia
 from repro.core import build_statics, init_state, load_jobs, run_episode, summary
 from repro.perfmodel import lm_jobs_workload, lm_training_job
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -58,4 +59,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -35,7 +35,13 @@ buffers. The per-replica computation — including the PRNG
 ``split``/``fold_in`` schedule, which happens on the host BEFORE the
 compiled call and is shared by both paths — is identical, so sharded
 final states / streams / telemetry are bit-identical to the vmapped
-path (pinned by ``tests/test_multidevice.py``).
+path run on each device's block of R/D replicas. Against one vmapped
+call over all R replicas the discrete state is equal and floats can
+differ by rounding, because the compiler picks a per-node reduction's
+order by batch size: on a TPU v5e, 64 vs 16 replicas moved
+``flops_integral``; on XLA:CPU the two agree bitwise (pinned by
+``tests/test_multidevice.py``) as long as every device holds at least
+two replicas, since a one-replica shard drops the unit batch dim.
 """
 
 from __future__ import annotations
@@ -64,7 +70,6 @@ from repro.sharding.specs import (
     fleet_pspecs,
     fleet_shardings,
     replicated_pspecs,
-    shard_map_compat,
 )
 from repro.utils import invariants
 from repro.utils.errors import ConfigError
@@ -152,6 +157,14 @@ def _fleet(cfg, statics, scenarios, policies, state, keys, n_steps,
     return jax.vmap(one)(scenarios, policies, keys, state)
 
 
+# Varying-manual-axes checking is off for the fleet's shard_map: the
+# per-replica program runs no collective, so the check has nothing to
+# guard, and with it on every fresh constant carry of the episode drivers'
+# while-loops/scans (tick counters, zeroed telemetry accumulators) would
+# have to be pcast to the replica axis to match its varying body output.
+_CHECK_VMA = False
+
+
 # Sharded twin of ``_fleet``: the same per-replica ``one`` under the same
 # inner ``vmap``, but partitioned across ``mesh``'s fleet axis by shard_map
 # so each device's R/D-lane while-loops run their own trip counts (no
@@ -178,12 +191,12 @@ def _fleet_sharded(cfg, statics, scenarios, policies, state, keys, n_steps,
     # per-leaf spec pytrees from sharding.specs: statics replicate, every
     # replica-batched operand splits its leading axis; the output prefix
     # spec P(axis) matches (SimState, StepOut|TelemetrySummary) alike
-    return shard_map_compat(
-        shard, mesh,
+    return jax.shard_map(
+        shard, mesh=mesh,
         in_specs=(replicated_pspecs(statics),
                   fleet_pspecs(scenarios, axis), fleet_pspecs(policies, axis),
                   fleet_pspecs(keys, axis), fleet_pspecs(state, axis)),
-        out_specs=PartitionSpec(axis),
+        out_specs=PartitionSpec(axis), check_vma=_CHECK_VMA,
     )(statics, scenarios, policies, keys, state)
 
 
@@ -230,12 +243,12 @@ def _fleet_segment_sharded(cfg, statics, scenarios, policies, state, acc,
 
         return jax.vmap(one)(scenarios, policies, state, acc)
 
-    return shard_map_compat(
-        shard, mesh,
+    return jax.shard_map(
+        shard, mesh=mesh,
         in_specs=(replicated_pspecs(statics),
                   fleet_pspecs(scenarios, axis), fleet_pspecs(policies, axis),
                   fleet_pspecs(state, axis), fleet_pspecs(acc, axis)),
-        out_specs=PartitionSpec(axis),
+        out_specs=PartitionSpec(axis), check_vma=_CHECK_VMA,
     )(statics, scenarios, policies, state, acc)
 
 
@@ -303,7 +316,9 @@ def run_fleet(
     a shard (see module docstring) and memory/donation happen per device.
     R must divide evenly by the mesh size (loud error otherwise — a
     silent pad would fabricate replicas whose summaries leak into sweep
-    statistics). Results are bit-identical to ``mesh=None``.
+    statistics). Results are bit-identical to ``mesh=None`` run on each
+    device's block of replicas, and equal to one ``mesh=None`` call over
+    all of them up to float rounding (see the module docstring).
 
     ``**kw`` forwards to ``run_episode``/``make_step`` — in particular
     ``summary_only=True`` returns per-replica ``TelemetrySummary`` with

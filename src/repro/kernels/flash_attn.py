@@ -111,7 +111,7 @@ def flash_attention_fwd(
     window: int = 0,
     block_q: int = 512,
     block_k: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, sq, h, hd = q.shape
     sk, kvh = k.shape[1], k.shape[2]

@@ -67,7 +67,7 @@ def selective_scan_pallas(
     *,
     chunk: int = 64,
     block_di: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ):
     ba, s, di = x.shape
     ds = A.shape[-1]

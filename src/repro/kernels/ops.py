@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-- ``interpret`` defaults to True off-TPU (this container is CPU-only; on a
-  real TPU set REPRO_PALLAS_INTERPRET=0 or pass interpret=False).
+- Interpret mode is chosen by the backend alone (``interpret_mode``): the
+  Pallas interpreter on the CPU backend, compiled Mosaic kernels on a TPU.
+  The ``*_pallas`` entry points take ``interpret`` without a default, so a
+  caller that bypasses these wrappers states the mode it wants.
 - ``flash_attention`` is differentiable: forward = Pallas kernel, backward
   = jax.vjp through the jnp chunked-online-softmax reference (identical
   math; the TPU backward kernel is an optimization left to ops parity).
@@ -9,8 +11,6 @@
 
 from __future__ import annotations
 
-import functools
-import os
 from functools import partial
 
 import jax
@@ -23,11 +23,9 @@ from repro.kernels.node_power import node_power_pallas, power_scatter_pallas
 from repro.kernels.rack_thermal import rack_thermal_pallas
 
 
-def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """True on the CPU backend (Pallas interpreter), False elsewhere."""
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -35,7 +33,7 @@ def _default_interpret() -> bool:
 def flash_attention(q, k, v, causal=True, window=0, block_q=512, block_k=1024):
     return flash_attention_fwd(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=_default_interpret(),
+        block_q=block_q, block_k=block_k, interpret=interpret_mode(),
     )
 
 
@@ -65,7 +63,7 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 @partial(jax.custom_vjp, nondiff_argnums=(5,))
 def selective_scan(x, dt, A, B, C, chunk=64):
     return selective_scan_pallas(
-        x, dt, A, B, C, chunk=chunk, interpret=_default_interpret()
+        x, dt, A, B, C, chunk=chunk, interpret=interpret_mode()
     )
 
 
@@ -94,7 +92,7 @@ def node_power(cpu_frac, gpu_frac, idle_w, cpu_dyn_w, gpu_dyn_w, node_up,
     return node_power_pallas(
         cpu_frac, gpu_frac, idle_w, cpu_dyn_w, gpu_dyn_w, node_up, node_max_w,
         rect_peak=rect_peak, rect_load=rect_load, rect_curv=rect_curv,
-        conv_eff=conv_eff, interpret=_default_interpret(),
+        conv_eff=conv_eff, interpret=interpret_mode(),
     )
 
 
@@ -107,7 +105,7 @@ def power_scatter(place_flat, cpu_abs, gpu_abs, cap_cpu, cap_gpu, idle_w,
         place_flat, cpu_abs, gpu_abs, cap_cpu, cap_gpu, idle_w, cpu_dyn_w,
         gpu_dyn_w, node_up, node_max_w,
         rect_peak=rect_peak, rect_load=rect_load, rect_curv=rect_curv,
-        conv_eff=conv_eff, interpret=_default_interpret(),
+        conv_eff=conv_eff, interpret=interpret_mode(),
     )
 
 
@@ -117,5 +115,5 @@ def rack_thermal(node_heat_w, node_rack, rack_outlet_c, supply_c, rack_r_th,
     Returns (new_outlet_c, rack_heat_w)."""
     return rack_thermal_pallas(
         node_heat_w, node_rack, rack_outlet_c, supply_c, rack_r_th,
-        alpha=alpha, interpret=_default_interpret(),
+        alpha=alpha, interpret=interpret_mode(),
     )

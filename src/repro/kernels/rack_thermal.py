@@ -35,7 +35,10 @@ def _rack_thermal_kernel(
     j = pl.program_id(0)
     ids = j * block_r + jax.lax.broadcasted_iota(jnp.int32, (1, block_r), 1)
     onehot = (rack_ref[...][:, None] == ids).astype(jnp.float32)   # (Np, br)
+    # HIGHEST: the MXU's default f32 pass rounds operands to bf16, which
+    # would cut rack heat to ~3 significant digits
     heat = jnp.dot(heat_ref[...][None, :].astype(jnp.float32), onehot,
+                   precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)[0]
     t = t_ref[...].astype(jnp.float32)
     t_ss = sup_ref[0] + heat * rth_ref[...]
@@ -52,8 +55,8 @@ def rack_thermal_pallas(
     rack_r_th: jax.Array,      # (R,)
     *,
     alpha: float,
+    interpret: bool,
     block_r: int = 128,
-    interpret: bool = True,
 ):
     """Returns (new_outlet_c, rack_heat_w), each (R,). vmap adds a leading
     grid dim, so vectorized replicas batch for free."""
@@ -82,6 +85,7 @@ def rack_thermal_pallas(
         out_specs=[blk, blk],
         out_shape=[jax.ShapeDtypeStruct((r + pad_r,), jnp.float32)] * 2,
         interpret=interpret,
+        name="rack_thermal",
     )(node_heat_w, node_rack, jnp.reshape(supply_c, (1,)).astype(jnp.float32),
       rack_outlet_c, rack_r_th)
     return new_t[:r], rheat[:r]

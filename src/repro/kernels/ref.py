@@ -156,12 +156,15 @@ def rack_thermal_ref(
     T' = T + alpha * (supply + heat * R_th - T). The node->rack reduction
     uses the same one-hot matmul as the Pallas kernel (not segment_sum) so
     both paths accumulate in the identical order and agree bitwise on CPU.
+    The contraction runs at ``Precision.HIGHEST``: the TPU default would
+    round the heat operand to bf16.
     Returns (new_outlet_c, rack_heat_w), each (R,).
     """
     r = rack_outlet_c.shape[0]
     onehot = (node_rack[:, None] == jnp.arange(r, dtype=jnp.int32)[None, :])
     heat = jnp.dot(node_heat_w[None, :].astype(jnp.float32),
                    onehot.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)[0]
     t_ss = supply_c + heat * rack_r_th
     new_t = rack_outlet_c + jnp.float32(alpha) * (t_ss - rack_outlet_c)

@@ -53,8 +53,7 @@ def make_fleet_mesh(n_devices: Optional[int] = None, *,
     """1-D mesh over the available devices for device-sharded fleet sweeps
     (``core.fleet.run_fleet(..., mesh=...)``) and shard_map PPO
     (``rl.distributed``): the replica/env axis partitions across ``axis``
-    and everything else replicates. Works on the pinned jax floor
-    (``axis_types`` is a newer keyword, so it is applied best-effort)."""
+    and everything else replicates."""
     devices = jax.devices()
     n = n_devices or len(devices)
     if n > len(devices):
@@ -62,10 +61,6 @@ def make_fleet_mesh(n_devices: Optional[int] = None, *,
             f"need {n} devices for a fleet mesh, have {len(devices)} — "
             "force host devices via "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    try:
-        return jax.make_mesh(
-            (n,), (axis,),
-            axis_types=(jax.sharding.AxisType.Auto,),
-            devices=devices[:n])
-    except (AttributeError, TypeError):
-        return jax.make_mesh((n,), (axis,), devices=devices[:n])
+    return jax.make_mesh((n,), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices[:n])

@@ -20,6 +20,7 @@ from repro.configs.sim import tiny_cluster, tx_gaia
 from repro.data import synth_workload
 from repro.envs import SchedEnv
 from repro.rl import PPOConfig, ppo_train
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -96,4 +97,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

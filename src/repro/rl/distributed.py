@@ -23,11 +23,12 @@ stat host sync (and the deprecated ``with mesh:`` context it needed) is
 gone. ``history`` carries the same per-iteration keys as ``ppo_train``
 (plus ``loss``), so benches can diff the two trainers row for row.
 
-Note the VMA detail: params enter the shard_map replicated, so they are
-pcast to "varying" before jax.grad — otherwise shard_map's AD inserts its
-own fp32 psum and the reduction (and the bytes) happen twice. On the
-pinned jax floor (no ``pcast``) the ``sharding.specs`` compat shims run
-shard_map with replication checking off, which has the same effect.
+Note the VMA detail: the shard_map runs with ``check_vma=False``. Params
+enter it replicated, and with varying-axes checking on, AD would insert
+its own fp32 psum for their gradients, so the reduction (and the bytes)
+would happen twice; unchecked, ``jax.grad`` stays local to the shard and
+``compressed_psum`` is the only reduction. It also lets the env's
+while-loops keep their constant initial carries.
 """
 
 from __future__ import annotations
@@ -44,7 +45,6 @@ from repro.optim.compress import compressed_psum
 from repro.rl.gae import gae
 from repro.rl.policy import ActorCritic
 from repro.rl.ppo import PPOConfig, _train_fingerprint, make_rollout, ppo_loss
-from repro.sharding.specs import pcast_varying, shard_map_compat
 from repro.utils.errors import ConfigError
 
 
@@ -67,7 +67,6 @@ def make_distributed_grad_step(
     def local(params, env_states, key, error):
         key = key[0]          # (1,) shard slice of the per-shard key array
         error = jax.tree.map(lambda e: e[0], error)
-        params = pcast_varying(params, axis)
         env_states, batch, last_val, ep = rollout(params, env_states, key)
         adv, ret = gae(batch.reward, batch.value, batch.done, last_val,
                        gamma=cfg.gamma, lam=cfg.lam)
@@ -98,9 +97,9 @@ def make_distributed_grad_step(
         return jax.tree.map(lambda _: spec, tree)
 
     def grad_step(params, env_states, keys, error):
-        return shard_map_compat(
+        return jax.shard_map(
             local,
-            mesh,
+            mesh=mesh,
             in_specs=(spec_like(params, P()),
                       spec_like(env_states, P(axis)),
                       P(axis),
@@ -109,6 +108,7 @@ def make_distributed_grad_step(
                        spec_like(env_states, P(axis)),
                        spec_like(error, P(axis)),
                        P()),
+            check_vma=False,
         )(params, env_states, keys, error)
 
     return grad_step

@@ -4,8 +4,6 @@ from repro.sharding.specs import (
     fleet_pspecs,
     fleet_shardings,
     param_pspecs,
-    pcast_varying,
     replicated_pspecs,
-    shard_map_compat,
     train_state_pspecs,
 )

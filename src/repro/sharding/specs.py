@@ -4,11 +4,8 @@ Sim half (the digital twin): replica-batched fleet pytrees
 (``SimState``/``Scenario``/``Policy``/``TelemetrySummary``) shard their
 leading replica axis across a 1-D fleet mesh — ``fleet_pspecs`` /
 ``replicated_pspecs`` / ``fleet_shardings`` below, consumed by
-``core.fleet.run_fleet(..., mesh=...)`` and ``rl.distributed``. The
-module also hosts the ``shard_map``/``pcast`` compat shims so every
-sharded caller works on the pinned jax floor (``jax.experimental.
-shard_map``, no ``pcast`` — replication checking is disabled there,
-which is exactly what keeps ``jax.grad`` local inside a shard).
+``core.fleet.run_fleet(..., mesh=...)`` and ``rl.distributed`` (both
+call ``jax.shard_map`` directly).
 
 LM half: parameter PartitionSpecs derived from param *names* and shapes.
 
@@ -67,31 +64,6 @@ def fleet_shardings(mesh, tree: Any, axis: str = FLEET_AXIS) -> Any:
     from jax.sharding import NamedSharding
 
     return jax.tree.map(lambda _: NamedSharding(mesh, P(axis)), tree)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where available, else the ``jax.experimental``
-    one with replication checking off (the pinned floor has no ``pcast``
-    to mark closed-over/replicated values varying, and ``check_rep=False``
-    is what keeps AD from inserting its own psum around ``jax.grad``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-
-def pcast_varying(tree: Any, axis: str) -> Any:
-    """Mark a replicated pytree shard-varying along ``axis`` (VMA) so
-    ``jax.grad`` inside a shard_map stays local. No-op on the jax floor:
-    there ``shard_map_compat`` already runs with ``check_rep=False``,
-    under which everything is treated as varying."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return tree
-    return jax.tree.map(lambda x: pcast(x, axis, to="varying"), tree)
 
 
 # ---------------------------------------------------------------- LM half

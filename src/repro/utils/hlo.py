@@ -131,3 +131,23 @@ def parse_collectives(hlo_text: str) -> CollectiveStats:
                 stats.add(kind, total)
                 break
     return stats
+
+
+# compiled HLO: %power_scatter.1 = (...) custom-call(...),
+#   custom_call_target="tpu_custom_call", ...
+_INSTR_NAME_RE = re.compile(r"\s*(?:ROOT\s+)?%([A-Za-z_][A-Za-z0-9_]*?)(?:\.\d+)?\s*=")
+
+
+def tpu_kernel_calls(hlo_text: str) -> Dict[str, int]:
+    """Names (the ``pallas_call`` ``name``) and counts of the compiled
+    Mosaic kernels in a TPU executable's HLO text
+    (``compiled.as_text()``). A kernel that ran through the Pallas
+    interpreter lowers to plain HLO and does not appear."""
+    calls: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _INSTR_NAME_RE.match(line)
+        name = m.group(1) if m else "?"
+        calls[name] = calls.get(name, 0) + 1
+    return calls

@@ -106,7 +106,7 @@ def test_node_power_sweep(e, n, block):
     from repro.kernels.node_power import node_power_pallas
 
     it, inp = node_power_pallas(cpu, gpu, idle, cd, gd, up, mx,
-                                block_n=block, **kw)
+                                block_n=block, interpret=True, **kw)
     it2, inp2 = ref.node_power_ref(cpu, gpu, idle, cd, gd, up, mx, **kw)
     np.testing.assert_allclose(np.asarray(it), np.asarray(it2), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(inp), np.asarray(inp2), rtol=1e-5)

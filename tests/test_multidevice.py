@@ -1,60 +1,12 @@
 """Sharded-path tests. jax locks the device count at first init, so these
-run in a subprocess with xla_force_host_platform_device_count=8.
-
-Skip guards are per-test CAPABILITY probes (hasattr on the exact APIs a
-test drives), not a module-wide version gate: the old blanket skip
-silently benched every test here whenever ANY newer API was missing, even
-the ones (mesh + NamedSharding jit) the pinned jax floor runs fine.
-"""
+run in a subprocess with xla_force_host_platform_device_count=8."""
 
 import os
 import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-def _has_shard_map_compat() -> bool:
-    # what sharding.specs.shard_map_compat needs: the public API or the
-    # jax.experimental fallback (run with check_rep=False there)
-    if hasattr(jax, "shard_map"):
-        return True
-    try:
-        from jax.experimental.shard_map import shard_map  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-_CAPS = {
-    "make_mesh": hasattr(jax, "make_mesh"),
-    "shard_map": hasattr(jax, "shard_map"),
-    "pcast": hasattr(jax.lax, "pcast"),
-    "shard_map_compat": _has_shard_map_compat(),
-}
-
-
-def _requires(*caps):
-    missing = [c for c in caps if not _CAPS[c]]
-    return pytest.mark.skipif(
-        bool(missing), reason=f"jax lacks {'/'.join(missing) or 'nothing'}")
-
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# prepended to every subprocess: build a mesh on any supported jax —
-# axis_types is a newer keyword, explicit sharding mode works without it
-_MESH_HELPER = """
-import jax
-
-def mk_mesh(shape, names):
-    try:
-        at = (jax.sharding.AxisType.Auto,) * len(shape)
-        return jax.make_mesh(shape, names, axis_types=at)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, names)
-"""
 
 
 def _run_sub(code: str, timeout=560):
@@ -62,20 +14,19 @@ def _run_sub(code: str, timeout=560):
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     r = subprocess.run(
-        [sys.executable, "-c", _MESH_HELPER + textwrap.dedent(code)],
+        [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,
     )
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     return r.stdout
 
 
-@_requires("make_mesh")
 def test_sharded_train_step_matches_unsharded():
     """FSDP+TP on a (2,4) mesh must produce the same loss trajectory as the
     single-device run (numerical tolerance)."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding
+        from jax.sharding import AxisType, NamedSharding
         from repro.configs import get_arch, reduced
         from repro.configs.base import ShapeConfig
         from repro.data.synth_lm import lm_batch_at
@@ -101,7 +52,8 @@ def test_sharded_train_step_matches_unsharded():
             ref.append(float(m["loss"]))
 
         # sharded
-        mesh = mk_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ctx = make_ctx(False, tp_size=4, dp_size=2)
         shape = ShapeConfig("t", 64, 8, "train")
         sps = train_state_pspecs(cfg, ctx, opt, mesh)
@@ -122,13 +74,12 @@ def test_sharded_train_step_matches_unsharded():
     assert "LOSSES" in out
 
 
-@_requires("make_mesh")
 def test_elastic_checkpoint_restore_across_mesh_shapes():
     """Checkpoint written from a (2,4) mesh restores onto (8,1) and (1,1)
     (elastic scaling / shrink-to-recover)."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np, tempfile
-        from jax.sharding import NamedSharding
+        from jax.sharding import AxisType, NamedSharding
         from repro.checkpoint import restore, save
         from repro.configs import get_arch, reduced
         from repro.models import init_params
@@ -142,14 +93,16 @@ def test_elastic_checkpoint_restore_across_mesh_shapes():
         state = {"params": params, "opt": opt.init(params), "step": jnp.int32(3)}
         d = tempfile.mkdtemp()
 
-        mesh1 = mk_mesh((2, 4), ("data", "model"))
+        mesh1 = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ctx1 = make_ctx(False, tp_size=4)
         ns1 = jax.tree.map(lambda p: NamedSharding(mesh1, p),
                            train_state_pspecs(cfg, ctx1, opt, mesh1))
         sharded = jax.device_put(state, ns1)
         save(d, 3, sharded)
 
-        mesh2 = mk_mesh((8, 1), ("data", "model"))
+        mesh2 = jax.make_mesh((8, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ctx2 = make_ctx(False, tp_size=1)
         ns2 = jax.tree.map(lambda p: NamedSharding(mesh2, p),
                            train_state_pspecs(cfg, ctx2, opt, mesh2))
@@ -163,7 +116,6 @@ def test_elastic_checkpoint_restore_across_mesh_shapes():
     assert "ELASTIC OK" in out
 
 
-@_requires("make_mesh")
 def test_fleet_with_thermals_shards_across_devices():
     """run_fleet with the cooling loop enabled, replica axis device-put
     across all 8 host devices: the sharded sweep must match the
@@ -171,7 +123,7 @@ def test_fleet_with_thermals_shards_across_devices():
     the standard accounting all thread through vmap + sharding)."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs.sim import tiny_cluster
         from repro.core import build_statics, init_state, load_jobs, run_fleet
         from repro.data import synth_workload
@@ -188,7 +140,7 @@ def test_fleet_with_thermals_shards_across_devices():
         fs_ref, tel_ref = run_fleet(cfg, statics, state, 400, "fcfs",
                                     scenarios=scns, summary_only=True)
 
-        mesh = mk_mesh((8,), ("replica",))
+        mesh = jax.make_mesh((8,), ("replica",), axis_types=(AxisType.Auto,))
         shard = lambda t: jax.device_put(
             t, jax.tree.map(lambda _: NamedSharding(mesh, P("replica")), t))
         fs_sh, tel_sh = run_fleet(cfg, statics, state, 400, "fcfs",
@@ -208,12 +160,9 @@ def test_fleet_with_thermals_shards_across_devices():
     assert "FLEET_THERMAL OK" in out
 
 
-@_requires("make_mesh", "shard_map_compat")
 def test_distributed_ppo_module_trains():
     """repro.rl.distributed: shard_map PPO on a SchedEnv fleet with int8
-    grad all-reduce, scanned outer loop, ppo_train-shaped history. Runs on
-    the jax floor through sharding.specs.shard_map_compat (formerly gated
-    on the public jax.shard_map/pcast APIs and skipped everywhere)."""
+    grad all-reduce, scanned outer loop, ppo_train-shaped history."""
     out = _run_sub("""
         import jax
         from repro.configs.sim import tiny_cluster
@@ -243,17 +192,15 @@ def test_distributed_ppo_module_trains():
     assert "DIST_PPO OK" in out
 
 
-@_requires("make_mesh", "shard_map_compat")
 def test_distributed_ppo_with_compressed_psum():
     """shard_map DP PPO gradient step with int8-compressed all-reduce."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.optim.compress import compressed_psum
         from repro.rl.policy import ActorCritic
-        from repro.sharding.specs import pcast_varying, shard_map_compat
 
-        mesh = mk_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         pol = ActorCritic(16, 4)
         params = pol.init(jax.random.key(0))
         obs = jax.random.normal(jax.random.key(1), (64, 16))
@@ -266,16 +213,15 @@ def test_distributed_ppo_with_compressed_psum():
 
         def step_local(params, obs, tgt):
             # mark params shard-varying so jax.grad stays LOCAL (otherwise
-            # shard_map AD inserts its own psum and we'd reduce twice; on
-            # the jax floor pcast_varying is a no-op and check_rep=False
-            # inside shard_map_compat has the same effect)
-            params = pcast_varying(params, "data")
+            # shard_map AD inserts its own psum and we'd reduce twice)
+            params = jax.tree.map(
+                lambda p: jax.lax.pcast(p, "data", to="varying"), params)
             g = local_grads(params, obs, tgt)
             g, _ = compressed_psum(g, "data")
             return g
 
-        step = shard_map_compat(
-            step_local, mesh,
+        step = jax.shard_map(
+            step_local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params), P("data"),
                       P("data")),
             out_specs=jax.tree.map(lambda _: P(), params))
@@ -289,14 +235,16 @@ def test_distributed_ppo_with_compressed_psum():
     assert "ERR" in out
 
 
-@_requires("make_mesh", "shard_map_compat")
 def test_sharded_fleet_bit_identical_to_vmapped():
     """run_fleet(mesh=...) vs the vmapped path, macro engine ON with
     thermals AND faults enabled: final states (including the PRNG
     streams), telemetry and fleet_summary must match BITWISE — the shard
     boundary only changes which device hosts each replica's while-loop,
     never a single op in it (the split/fold_in key schedule runs on the
-    host before the compiled call, shared by both paths)."""
+    host before the compiled call, shared by both paths). Two replicas
+    per device: a one-replica shard lets XLA:CPU drop the unit batch dim
+    and re-fuse a per-node reduction, which can move one accumulator by an
+    ulp against the batched program."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.sim import tiny_cluster
@@ -313,7 +261,7 @@ def test_sharded_fleet_bit_identical_to_vmapped():
         jobs, bank = synth_workload(cfg, 32, 900.0, seed=0)
         statics = build_statics(cfg, bank)
         st = load_jobs(init_state(cfg, statics, jax.random.key(0)), jobs)
-        scns = sample_scenarios(cfg, 8, seed=7)
+        scns = sample_scenarios(cfg, 16, seed=7)
 
         sv, tv = run_fleet(cfg, statics, st, 400, "fcfs", scenarios=scns,
                            macro=True, summary_only=True)
@@ -339,7 +287,6 @@ def test_sharded_fleet_bit_identical_to_vmapped():
     assert "SHARDED_BITWISE OK" in out
 
 
-@_requires("make_mesh", "shard_map_compat")
 def test_sharded_fleet_uneven_replicas_loud_error():
     """R not divisible by the mesh size must raise before tracing — a
     silent pad would fabricate replicas whose summaries pollute sweep

@@ -125,7 +125,7 @@ def test_power_scatter_ref_matches_pallas_kernel():
     kw = dict(rect_peak=0.965, rect_load=0.55, rect_curv=0.12,
               conv_eff=0.975)
     got = power_scatter_pallas(place, cabs, gabs, capc, capg, idle, cd, gd,
-                               up, mx, block_n=64, **kw)
+                               up, mx, block_jk=128, interpret=True, **kw)
     want = ref.power_scatter_ref(place, cabs, gabs, capc, capg, idle, cd,
                                  gd, up, mx, **kw)
     for g, w in zip(got, want):

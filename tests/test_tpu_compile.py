@@ -1,0 +1,129 @@
+"""Compile the twin's kernels and TX-GAIA step for a described v5e chip.
+
+Nothing runs: ``get_topology_desc`` describes a TPU v5e that is not
+attached, and XLA's TPU compiler (with Mosaic for the Pallas kernels)
+compiles for it here. That catches what interpret mode hides — block
+shapes Mosaic cannot tile, more VMEM than a kernel may use, a program
+larger than the chip's 16 GiB HBM — without a chip.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.sim import tx_gaia
+from repro.core import build_statics, init_state, make_macro_step, make_step
+from repro.core.sim import _telem_zero
+from repro.kernels.node_power import node_power_pallas, power_scatter_pallas
+from repro.kernels.rack_thermal import rack_thermal_pallas
+from repro.utils.hlo import tpu_kernel_calls
+
+V5E_HBM_BYTES = 16 * 2**30
+RECT = dict(rect_peak=0.965, rect_load=0.55, rect_curv=0.12, conv_eff=0.975)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip is written to a persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: cannot describe
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+def _sds(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                       sharding=sharding), tree)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
+    assert used < V5E_HBM_BYTES, f"{used} bytes do not fit a v5e"
+    return compiled
+
+
+def test_power_scatter_compiles_at_tx_gaia_width(one_chip):
+    jk, n = 512 * 64, 672           # tx_gaia(): max_jobs x max_nodes_per_job
+    f = lambda *a: power_scatter_pallas(*a, **RECT, interpret=False)
+    args = ([jax.ShapeDtypeStruct((jk,), jnp.int32, sharding=one_chip)]
+            + [jax.ShapeDtypeStruct((jk,), jnp.float32, sharding=one_chip)] * 2
+            + [jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)] * 7)
+    c = _compile(f, *args)
+    assert tpu_kernel_calls(c.as_text()) == {"power_scatter": 1}
+
+
+def test_rack_thermal_compiles_at_tx_gaia_width(one_chip):
+    n, r = 672, 21
+    f = lambda *a: rack_thermal_pallas(*a, alpha=0.01, interpret=False)
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    c = _compile(f, S((n,)), S((n,), jnp.int32), S((r,)), S(()), S((r,)))
+    assert tpu_kernel_calls(c.as_text()) == {"rack_thermal": 1}
+
+
+def test_node_power_compiles_for_a_64_env_batch(one_chip):
+    e, n = 64, 672
+    f = lambda *a: node_power_pallas(*a, **RECT, interpret=False)
+    S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                           sharding=one_chip)
+    c = _compile(f, S((e, n)), S((e, n)), S((n,)), S((n,)), S((n,)),
+                 S((e, n)), S((n,)))
+    assert tpu_kernel_calls(c.as_text()) == {"node_power": 1}
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+def test_tx_gaia_step_compiles(one_chip, kernels, monkeypatch):
+    """The per-tick step at ``tx_gaia()`` defaults with thermals on; with
+    ``kernels`` the power and thermal reductions go through the compiled
+    Pallas kernels (interpret mode follows the default backend, which is
+    the CPU here, so the test steers it off)."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    cfg = tx_gaia(thermal_enabled=True)
+    statics = build_statics(cfg)
+    state = init_state(cfg, statics, jax.random.key(0))
+
+    def step(st, s):
+        return make_step(cfg, st, "fcfs", use_power_kernel=kernels,
+                         use_thermal_kernel=kernels)(s, jnp.int32(-1))
+
+    c = _compile(step, _sds(statics, one_chip), _sds(state, one_chip))
+    want = {"power_scatter": 1, "rack_thermal": 1} if kernels else {}
+    assert tpu_kernel_calls(c.as_text()) == want
+
+
+def test_tx_gaia_macro_step_compiles(one_chip):
+    cfg = tx_gaia()
+    statics = build_statics(cfg)
+    state = init_state(cfg, statics, jax.random.key(0))
+    acc = _telem_zero(cfg.resilience_on, cfg.serving_on)
+
+    def macro(st, s, a):
+        return make_macro_step(cfg, st, "fcfs")(s, a, 3600)
+
+    _compile(macro, _sds(statics, one_chip), _sds(state, one_chip),
+             _sds(acc, one_chip))
