@@ -52,6 +52,7 @@ if jax.config.jax_platforms and "cpu" not in jax.config.jax_platforms:
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from chipbench.clock import CompileClock  # noqa: E402
 from repro.configs.sim import tx_gaia  # noqa: E402
 from repro.core import (  # noqa: E402
     build_statics,
@@ -111,39 +112,6 @@ FULL_STACK = dict(
 
 
 # ----------------------------------------------------------------- timing
-class CompileClock:
-    """Splits a run's wall time into compile seconds (the union of JAX's
-    trace, lower and backend-compile spans; they nest for inner jits) and
-    steady seconds (the rest)."""
-
-    _EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-               "/jax/core/compile/jaxpr_to_mlir_module_duration",
-               "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        self.spans = []
-
-    def _listen(self, event, start, end, **_):
-        if event in self._EVENTS:
-            self.spans.append((start, end))
-
-    def compile_s(self, t0: float, t1: float) -> float:
-        total, covered = 0.0, t0
-        for s, e in sorted(self.spans):
-            s, e = max(s, covered), min(e, t1)
-            if e > s:
-                total += e - s
-                covered = e
-        return total
-
-    def __enter__(self):
-        jax.monitoring.register_event_time_span_listener(self._listen)
-        return self
-
-    def __exit__(self, *exc):
-        jax.monitoring.unregister_event_time_span_listener(self._listen)
-
-
 class Phase:
     """Accumulates one phase's timed runs and checks into its JSON line."""
 

@@ -42,6 +42,12 @@ order by batch size: on a TPU v5e, 64 vs 16 replicas moved
 ``flops_integral``; on XLA:CPU the two agree bitwise (pinned by
 ``tests/test_multidevice.py``) as long as every device holds at least
 two replicas, since a one-replica shard drops the unit batch dim.
+
+Host spans: ``run_fleet`` wraps its argument checks and replica batching,
+the compiled call's dispatch and the ``REPRO_CHECKIFY`` audit in the
+profiler spans ``host.fleet.prepare``, ``host.fleet.call`` and
+``host.fleet.audit`` (``jax.profiler.TraceAnnotation``), so a trace names
+what the host was doing while the device waited.
 """
 
 from __future__ import annotations
@@ -262,6 +268,70 @@ def shard_fleet(tree, mesh, axis: str = FLEET_AXIS):
     return jax.device_put(tree, fleet_shardings(mesh, tree, axis))
 
 
+def _fleet_inputs(statics, state, scheduler, scenarios, policies,
+                  workloads):
+    """``run_fleet``'s argument checks and replica batching: (scheduler,
+    batched scenarios, batched policies or None, replica-batched state,
+    per-replica keys)."""
+    if policies is not None and scheduler is not None:
+        raise ConfigError(
+            f"both scheduler={scheduler!r} and policies= given — policies "
+            "carry the selection stage, so the scheduler name would be "
+            "silently ignored; pass exactly one")
+    if scheduler is None:
+        scheduler = "fcfs"
+    if policies is not None:
+        policies = _ensure_batched_policies(policies)
+        P = int(jnp.shape(policies.select)[0])
+        if scenarios is None:
+            scenarios = stack_scenarios([statics.scenario] * P)
+        else:
+            scenarios = _ensure_batched(scenarios)
+            if n_replicas(scenarios) != P:
+                raise ConfigError(
+                    f"{P} policies vs {n_replicas(scenarios)} scenarios — "
+                    "axes must match; build the cross product with "
+                    "policy_scenario_grid(policies, scenarios)")
+    elif scenarios is None:
+        scenarios = stack_scenarios([statics.scenario])
+    else:
+        scenarios = _ensure_batched(scenarios)
+    R = n_replicas(scenarios)
+    if jnp.ndim(state.t) == 0:
+        keys = jax.random.split(state.key, R)
+        state = jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (R,) + jnp.shape(a)), state)
+    else:
+        if int(jnp.shape(state.t)[0]) != R:
+            raise ConfigError(
+                f"batched state has {jnp.shape(state.t)[0]} replicas, "
+                f"scenarios have {R}")
+        # advance each replica's stream into a FRESH buffer: state and keys
+        # are both donated, so aliasing keys to the state.key leaf would
+        # donate one buffer twice
+        keys = jax.vmap(lambda k: jax.random.fold_in(k, 1))(state.key)
+    if workloads is not None:
+        if jnp.ndim(statics.cpu_trace) != 3:
+            raise ConfigError(
+                "workloads= needs a banked Statics trace ((W, J, Q) "
+                "cpu_trace, e.g. from data.stack_workloads); this statics "
+                "carries a single unbatched workload")
+        ids_host = np.asarray(workloads, np.int32)   # host data: check here
+        if ids_host.shape != (R,):
+            raise ConfigError(
+                f"workloads has shape {ids_host.shape}, expected ({R},) — "
+                "one bank id per replica")
+        W = statics.cpu_trace.shape[0]
+        lo, hi = int(ids_host.min()), int(ids_host.max())
+        if lo < 0 or hi >= W:
+            raise ConfigError(
+                f"workload ids must be in [0, {W}) for this bank; got "
+                f"[{lo}, {hi}] — an out-of-range id would silently clamp "
+                "to the edge slice")
+        state = state._replace(workload=jnp.asarray(ids_host))
+    return scheduler, scenarios, policies, state, keys
+
+
 def run_fleet(
     cfg: SimConfig,
     statics: Statics,
@@ -338,62 +408,10 @@ def run_fleet(
 
     Returns (final_states, outs) with a leading replica axis on every leaf.
     """
-    if policies is not None and scheduler is not None:
-        raise ConfigError(
-            f"both scheduler={scheduler!r} and policies= given — policies "
-            "carry the selection stage, so the scheduler name would be "
-            "silently ignored; pass exactly one")
-    if scheduler is None:
-        scheduler = "fcfs"
-    if policies is not None:
-        policies = _ensure_batched_policies(policies)
-        P = int(jnp.shape(policies.select)[0])
-        if scenarios is None:
-            scenarios = stack_scenarios([statics.scenario] * P)
-        else:
-            scenarios = _ensure_batched(scenarios)
-            if n_replicas(scenarios) != P:
-                raise ConfigError(
-                    f"{P} policies vs {n_replicas(scenarios)} scenarios — "
-                    "axes must match; build the cross product with "
-                    "policy_scenario_grid(policies, scenarios)")
-    elif scenarios is None:
-        scenarios = stack_scenarios([statics.scenario])
-    else:
-        scenarios = _ensure_batched(scenarios)
-    R = n_replicas(scenarios)
-    if jnp.ndim(state.t) == 0:
-        keys = jax.random.split(state.key, R)
-        state = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (R,) + jnp.shape(a)), state)
-    else:
-        if int(jnp.shape(state.t)[0]) != R:
-            raise ConfigError(
-                f"batched state has {jnp.shape(state.t)[0]} replicas, "
-                f"scenarios have {R}")
-        # advance each replica's stream into a FRESH buffer: state and keys
-        # are both donated, so aliasing keys to the state.key leaf would
-        # donate one buffer twice
-        keys = jax.vmap(lambda k: jax.random.fold_in(k, 1))(state.key)
-    if workloads is not None:
-        if jnp.ndim(statics.cpu_trace) != 3:
-            raise ConfigError(
-                "workloads= needs a banked Statics trace ((W, J, Q) "
-                "cpu_trace, e.g. from data.stack_workloads); this statics "
-                "carries a single unbatched workload")
-        ids_host = np.asarray(workloads, np.int32)   # host data: check here
-        if ids_host.shape != (R,):
-            raise ConfigError(
-                f"workloads has shape {ids_host.shape}, expected ({R},) — "
-                "one bank id per replica")
-        W = statics.cpu_trace.shape[0]
-        lo, hi = int(ids_host.min()), int(ids_host.max())
-        if lo < 0 or hi >= W:
-            raise ConfigError(
-                f"workload ids must be in [0, {W}) for this bank; got "
-                f"[{lo}, {hi}] — an out-of-range id would silently clamp "
-                "to the edge slice")
-        state = state._replace(workload=jnp.asarray(ids_host))
+    with jax.profiler.TraceAnnotation("host.fleet.prepare"):
+        scheduler, scenarios, policies, state, keys = _fleet_inputs(
+            statics, state, scheduler, scenarios, policies, workloads)
+        R = n_replicas(scenarios)
     kw_items = tuple(sorted(kw.items()))
     if snapshot_every_s is not None or resume_from is not None \
             or snapshot_dir is not None:
@@ -404,10 +422,7 @@ def run_fleet(
             scheduler, kw, mesh=mesh, mesh_axis=mesh_axis,
             snapshot_every_s=snapshot_every_s, snapshot_dir=snapshot_dir,
             resume_from=resume_from, snapshot_keep=snapshot_keep)
-        if invariants.enabled():
-            invariants.check_state(cfg, statics, out[0])
-        return out
-    if mesh is not None:
+    elif mesh is not None:
         if mesh_axis not in mesh.shape:
             raise ConfigError(
                 f"mesh has axes {tuple(mesh.shape)}, no {mesh_axis!r} — "
@@ -419,17 +434,21 @@ def run_fleet(
                 f"{mesh_axis!r}-axis devices — a silent pad would "
                 "fabricate replicas; pick R as a multiple of the mesh "
                 "size or shrink the mesh (make_fleet_mesh(n_devices=...))")
-        out = _fleet_sharded(cfg, statics, scenarios, policies, state, keys,
-                             n_steps, scheduler, kw_items, mesh, mesh_axis)
+        with jax.profiler.TraceAnnotation("host.fleet.call"):
+            out = _fleet_sharded(cfg, statics, scenarios, policies, state,
+                                 keys, n_steps, scheduler, kw_items, mesh,
+                                 mesh_axis)
     else:
-        out = _fleet(cfg, statics, scenarios, policies, state, keys, n_steps,
-                     scheduler, kw_items)
+        with jax.profiler.TraceAnnotation("host.fleet.call"):
+            out = _fleet(cfg, statics, scenarios, policies, state, keys,
+                         n_steps, scheduler, kw_items)
     if invariants.enabled():
         # post-hoc eager audit of every replica's final state (the checks
         # broadcast over the leading replica axis); the per-step checkify
         # suite only instruments un-traced run_episode calls, so this is
         # what REPRO_CHECKIFY buys on the vmapped fleet path
-        invariants.check_state(cfg, statics, out[0])
+        with jax.profiler.TraceAnnotation("host.fleet.audit"):
+            invariants.check_state(cfg, statics, out[0])
     return out
 
 

@@ -20,10 +20,17 @@ Step order (matches RAPS' fixed-dt loop):
   3. scheduling: up to `starts_per_step` dispatch attempts via the policy
   4. progress running jobs (network-congestion-aware rate)
   5. power chain + energy/carbon/stat accumulation
+
+Each stage of the tick and of the macro step is traced under a
+``jax.named_scope`` (``tick.*``, ``macro.*``; docs/performance.md,
+"Profiling the twin by stage"), so every op of the compiled program
+carries its stage in ``op_name``. The names are trace-time metadata only:
+the compiled program is the same with or without them.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -98,6 +105,19 @@ class StepOut(NamedTuple):
     srv_queue_len: jax.Array | None = None   # post-flow queued mass
     srv_active_nodes: jax.Array | None = None
     srv_lat_hist_step: jax.Array | None = None  # (8,) per-tick histogram
+
+
+def _scoped(name: str):
+    """Decorator: the function traced under ``jax.named_scope(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+
+        return scoped
+
+    return wrap
 
 
 def _parse_weights(reward_weights) -> Tuple[
@@ -179,31 +199,33 @@ def _make_tail(cfg: SimConfig, statics: Statics, reward_weights,
             # Only the node DYNAMIC power throttles — idle power burns at
             # any clock — and input power scales with IT (the rectifier-eta
             # shift under derating is second-order; docs/thermal.md).
-            th_r = thm.rack_throttle(cfg, state.rack_outlet_c)   # (R,)
-            node_th = th_r[statics.node_rack]                    # (N,)
-            node_idle = statics.idle_w * state.node_up
-            node_dyn = jnp.maximum(p.node_it_w - node_idle, 0.0)
-            node_it = node_idle + node_th * node_dyn
-            node_input = p.node_input_w * (
-                node_it / jnp.maximum(p.node_it_w, 1e-9))
-            it_w = jnp.sum(node_it)
-            input_w = jnp.sum(node_input)
-            dyn_tot = jnp.sum(node_dyn)
-            gscale = jnp.where(
-                dyn_tot > 0.0,
-                jnp.sum(node_th * node_dyn) / jnp.maximum(dyn_tot, 1e-9),
-                1.0)
-            cop = thm.cooling_cop(cfg, wb, it_w / nameplate)
-            cooling_w = input_w / cop
-            facility_w = input_w + cooling_w
-            pue = jnp.where(it_w > 1.0,
-                            facility_w / jnp.maximum(it_w, 1.0), 1.0)
-            p = p._replace(
-                node_it_w=node_it, node_input_w=node_input, it_w=it_w,
-                input_w=input_w, cooling_w=cooling_w,
-                facility_w=facility_w, pue=pue, gflops=p.gflops * gscale)
-            # synchronous ranks run at the slowest clock over a job's nodes
-            rate = rate * thm.job_thermal_rate(state, statics, node_th)
+            with jax.named_scope("tick.thermal"):
+                th_r = thm.rack_throttle(cfg, state.rack_outlet_c)   # (R,)
+                node_th = th_r[statics.node_rack]                    # (N,)
+                node_idle = statics.idle_w * state.node_up
+                node_dyn = jnp.maximum(p.node_it_w - node_idle, 0.0)
+                node_it = node_idle + node_th * node_dyn
+                node_input = p.node_input_w * (
+                    node_it / jnp.maximum(p.node_it_w, 1e-9))
+                it_w = jnp.sum(node_it)
+                input_w = jnp.sum(node_input)
+                dyn_tot = jnp.sum(node_dyn)
+                gscale = jnp.where(
+                    dyn_tot > 0.0,
+                    jnp.sum(node_th * node_dyn) / jnp.maximum(dyn_tot, 1e-9),
+                    1.0)
+                cop = thm.cooling_cop(cfg, wb, it_w / nameplate)
+                cooling_w = input_w / cop
+                facility_w = input_w + cooling_w
+                pue = jnp.where(it_w > 1.0,
+                                facility_w / jnp.maximum(it_w, 1.0), 1.0)
+                p = p._replace(
+                    node_it_w=node_it, node_input_w=node_input, it_w=it_w,
+                    input_w=input_w, cooling_w=cooling_w,
+                    facility_w=facility_w, pue=pue, gflops=p.gflops * gscale)
+                # synchronous ranks run at the slowest clock over a job's
+                # nodes
+                rate = rate * thm.job_thermal_rate(state, statics, node_th)
         else:
             # telemetry-only mirror of power.finish_power's static plant
             # (dead for the accumulators, so the legacy math is untouched)
@@ -316,15 +338,17 @@ def _make_tail(cfg: SimConfig, statics: Statics, reward_weights,
             # conversion losses, all of it room heat) relaxes each rack
             # toward its loaded steady state. Committed LAST, so this
             # tick's derate used the pre-update temps (the one-tick lag).
-            new_t, _ = thm.rack_thermal_update(
-                cfg, statics, state.rack_outlet_c, p.node_input_w * r,
-                thm.supply_temp(cfg, wb), use_kernel=use_thermal_kernel)
-            th_step = jnp.where(jnp.any(th_r < 1.0), cfg.dt, 0.0)
-            state = state._replace(
-                rack_outlet_c=new_t,
-                thermal_throttle_s=state.thermal_throttle_s + th_step,
-                peak_rack_c=jnp.maximum(state.peak_rack_c, jnp.max(new_t)))
-            rack_max = jnp.max(new_t)
+            with jax.named_scope("tick.thermal"):
+                new_t, _ = thm.rack_thermal_update(
+                    cfg, statics, state.rack_outlet_c, p.node_input_w * r,
+                    thm.supply_temp(cfg, wb), use_kernel=use_thermal_kernel)
+                th_step = jnp.where(jnp.any(th_r < 1.0), cfg.dt, 0.0)
+                state = state._replace(
+                    rack_outlet_c=new_t,
+                    thermal_throttle_s=state.thermal_throttle_s + th_step,
+                    peak_rack_c=jnp.maximum(state.peak_rack_c,
+                                            jnp.max(new_t)))
+                rack_max = jnp.max(new_t)
         else:
             rack_max = jnp.max(state.rack_outlet_c)
             th_step = jnp.float32(0.0)
@@ -375,7 +399,7 @@ def _make_tail(cfg: SimConfig, statics: Statics, reward_weights,
         )
         return state, out
 
-    return tail
+    return _scoped("tick.tail")(tail)
 
 
 def _counts_and_util(state: SimState, statics: Statics):
@@ -521,67 +545,78 @@ def make_step(
             return eager_place(_dispatch_view(s), statics, j)
 
     def step(state: SimState, action: jax.Array) -> Tuple[SimState, StepOut]:
-        state = state._replace(t=state.t + cfg.dt)
+        # the clock advance is named with the completions, which stamp it
+        with jax.named_scope("tick.complete"):
+            state = state._replace(t=state.t + cfg.dt)
         if cfg.resilience_on:
-            state, killed_now, lost_now = flt.apply_faults(cfg, state,
-                                                           statics)
+            with jax.named_scope("tick.faults"):
+                state, killed_now, lost_now = flt.apply_faults(cfg, state,
+                                                               statics)
         else:
             killed_now = lost_now = None
         if cfg.serving_on:
             # discrete overload ladder: autoscale, retry re-injection,
             # timeout/admission/shed cascade (full event ticks only;
             # bitwise fixpoint on quiet ticks — core.serving)
-            state, shed_now, dropped_now, retried_now = srv.apply_serving(
-                cfg, state, statics)
+            with jax.named_scope("tick.serving"):
+                state, shed_now, dropped_now, retried_now = \
+                    srv.apply_serving(cfg, state, statics)
         else:
             shed_now = dropped_now = retried_now = None
-        state, n_done = _complete_jobs(cfg, state)
+        with jax.named_scope("tick.complete"):
+            state, n_done = _complete_jobs(cfg, state)
 
         # --- dispatch
-        if not policy_mode and scheduler == "none":
-            pass    # idle sub-step: no selection, no placement
-        elif not policy_mode and scheduler == "rl":
-            cands = sched.rl_candidates(cfg, state)          # (k,)
-            k = cands.shape[0]
-            job = jnp.where(action < k, cands[jnp.clip(action, 0, k - 1)], -1)
-            state = _try_start(cfg, state, job, place_fn)
-        else:
-            # single fori_loop wavefront: the jaxpr holds ONE copy of the
-            # select+place body regardless of starts_per_step (the unrolled
-            # loop grew trace size/compile time linearly with attempts).
-            # Selection sees the placement backend's node eligibility
-            # (PLACEMENT_MASKS registry, e.g. partition tags) so it never
-            # picks a job placement rejects. Eligibility depends only on
-            # part/node_type — loop-invariant, so it is computed once per
-            # step, not per dispatch attempt.
-            if policy_mode:
-                node_mask = plc.placement_node_mask(state, statics,
-                                                    scheduler.place)
-
-                def select(c, s):
-                    return sched.select_job(c, _dispatch_view(s), statics,
-                                            scheduler.select, node_mask)
+        with jax.named_scope("tick.dispatch"):
+            if not policy_mode and scheduler == "none":
+                pass    # idle sub-step: no selection, no placement
+            elif not policy_mode and scheduler == "rl":
+                cands = sched.rl_candidates(cfg, state)          # (k,)
+                k = cands.shape[0]
+                job = jnp.where(action < k,
+                                cands[jnp.clip(action, 0, k - 1)], -1)
+                state = _try_start(cfg, state, job, place_fn)
             else:
-                eager_select = sched.SCHEDULERS[scheduler]
-                mask_fn = plc.PLACEMENT_MASKS[placement]
-                node_mask = None if mask_fn is None else mask_fn(state,
-                                                                 statics)
+                # single fori_loop wavefront: the jaxpr holds ONE copy of
+                # the select+place body regardless of starts_per_step (the
+                # unrolled loop grew trace size/compile time linearly with
+                # attempts). Selection sees the placement backend's node
+                # eligibility (PLACEMENT_MASKS registry, e.g. partition
+                # tags) so it never picks a job placement rejects.
+                # Eligibility depends only on part/node_type —
+                # loop-invariant, so it is computed once per step, not per
+                # dispatch attempt.
+                if policy_mode:
+                    node_mask = plc.placement_node_mask(state, statics,
+                                                        scheduler.place)
 
-                def select(c, s):
-                    return eager_select(c, _dispatch_view(s), statics,
-                                        node_mask)
+                    def select(c, s):
+                        return sched.select_job(c, _dispatch_view(s), statics,
+                                                scheduler.select, node_mask)
+                else:
+                    eager_select = sched.SCHEDULERS[scheduler]
+                    mask_fn = plc.PLACEMENT_MASKS[placement]
+                    node_mask = None if mask_fn is None else mask_fn(state,
+                                                                     statics)
 
-            def dispatch(_, s: SimState) -> SimState:
-                return _try_start(cfg, s, select(cfg, s), place_fn)
+                    def select(c, s):
+                        return eager_select(c, _dispatch_view(s), statics,
+                                            node_mask)
 
-            state = jax.lax.fori_loop(0, starts_per_step, dispatch, state)
+                def dispatch(_, s: SimState) -> SimState:
+                    return _try_start(cfg, s, select(cfg, s), place_fn)
+
+                state = jax.lax.fori_loop(0, starts_per_step, dispatch, state)
 
         # --- power chain (pre-throttle) + progress rate + telemetry counts;
         # the shared accounting tail does the rest (signals, throttle,
         # progress, accumulation, reward)
-        p: PowerOut = compute_power(cfg, state, statics, use_kernel=use_power_kernel)
-        rate, net_load = congestion_slowdown(cfg, state, statics)
-        queued, running, util = _counts_and_util(state, statics)
+        with jax.named_scope("tick.power"):
+            p: PowerOut = compute_power(cfg, state, statics,
+                                        use_kernel=use_power_kernel)
+        with jax.named_scope("tick.load"):
+            rate, net_load = congestion_slowdown(cfg, state, statics)
+            queued, running, util = _counts_and_util(state, statics)
         return tail(state, p, rate, net_load, n_done, queued, running, util,
                     killed_now, lost_now, shed_now, dropped_now, retried_now)
 
@@ -668,6 +703,7 @@ def _telem_zero(resilience_on: bool = True,
     return acc
 
 
+@_scoped("tick.telemetry")
 def _telem_update(acc: TelemetrySummary, out: StepOut,
                   macro_inc: jax.Array | float = 1.0,
                   resilience_on: bool = True,
@@ -982,7 +1018,10 @@ def make_macro_step(
             return _telem_update(acc, out, macro_inc,
                                  resilience_on=cfg.resilience_on,
                                  serving_on=cfg.serving_on)
+    else:
+        update = _scoped("tick.telemetry")(update)
 
+    @_scoped("macro.power_chunk")
     def power_chunk(s: SimState, cnt):
         """(ts, PowerOut-with-leading-C-axis) for the next C ticks under a
         frozen machine state: utilization only drifts through the
@@ -1011,46 +1050,49 @@ def make_macro_step(
         return ts, p
 
     def macro_step(state: SimState, acc, max_ticks):
-        was_queued = state.jstate == QUEUED
-        state, out = step(state, jnp.int32(-1))
-        acc = update(acc, out, 1.0)
-        started = jnp.any(was_queued & (state.jstate == RUNNING))
+        with jax.named_scope("macro.event"):
+            was_queued = state.jstate == QUEUED
+            state, out = step(state, jnp.int32(-1))
+            acc = update(acc, out, 1.0)
+            started = jnp.any(was_queued & (state.jstate == RUNNING))
 
         # --- segment constants (all provably frozen across quiet ticks).
-        # NB net_load carries a cross-job reduction: XLA may fuse it
-        # differently here than in the per-tick program, so telemetry
-        # means can skew an ulp vs per-tick runs (the documented
-        # float-accumulation tolerance); job/queue state never consumes it
-        rate, net_load = congestion_slowdown(cfg, state, statics)
-        next_event_t, visible_now, k_time, _ = _horizon_parts(
-            cfg, state, statics, rate, dispatch_on, replay_gated,
-            eligibility_vis, horizon_cap)
-        # dispatch gate: if the full tick started something AND jobs are
-        # still visible, the leftovers may now be servable — keep per-tick
-        # stepping. A start that DRAINED the queue, or a no-start with a
-        # visible queue (proven unservable: selection picks are
-        # t-independent for a frozen machine state, EASY's backfill window
-        # only shrinks, replay-eligibility crossings are event
-        # boundaries), both allow fast-forward. Completions are peeked per
-        # tick (authoritative), so the budget only carries the
-        # deterministic time-event horizon.
-        k_quiet = jnp.minimum(k_time, max_ticks - 1)
-        if thermal_gate:
-            # conservative thermal-crossing horizon (belt to the per-tick
-            # detection's suspenders: keeps segments from even entering
-            # the neighborhood of a trip crossing un-checked)
-            k_quiet = jnp.minimum(k_quiet, thm.thermal_crossing_horizon(
-                cfg, statics, state, horizon_cap))
-        blocked = started & visible_now
-        if cfg.serving_on:
-            # arrival-envelope bound on queue-threshold crossings, and
-            # stay per-tick while the queue sits over a threshold (the
-            # next tick's sweep WILL move mass): overload IS the event
-            k_quiet = jnp.minimum(k_quiet, srv.serving_crossing_horizon(
-                cfg, state, statics, horizon_cap))
-            blocked = blocked | srv.serving_trigger(cfg, state)
-        budget = jnp.where(blocked, 0, k_quiet)
-        queued, running, util = _counts_and_util(state, statics)
+        with jax.named_scope("macro.horizon"):
+            # NB net_load carries a cross-job reduction: XLA may fuse it
+            # differently here than in the per-tick program, so telemetry
+            # means can skew an ulp vs per-tick runs (the documented
+            # float-accumulation tolerance); job/queue state never
+            # consumes it
+            rate, net_load = congestion_slowdown(cfg, state, statics)
+            next_event_t, visible_now, k_time, _ = _horizon_parts(
+                cfg, state, statics, rate, dispatch_on, replay_gated,
+                eligibility_vis, horizon_cap)
+            # dispatch gate: if the full tick started something AND jobs are
+            # still visible, the leftovers may now be servable — keep per-tick
+            # stepping. A start that DRAINED the queue, or a no-start with a
+            # visible queue (proven unservable: selection picks are
+            # t-independent for a frozen machine state, EASY's backfill window
+            # only shrinks, replay-eligibility crossings are event
+            # boundaries), both allow fast-forward. Completions are peeked per
+            # tick (authoritative), so the budget only carries the
+            # deterministic time-event horizon.
+            k_quiet = jnp.minimum(k_time, max_ticks - 1)
+            if thermal_gate:
+                # conservative thermal-crossing horizon (belt to the per-tick
+                # detection's suspenders: keeps segments from even entering
+                # the neighborhood of a trip crossing un-checked)
+                k_quiet = jnp.minimum(k_quiet, thm.thermal_crossing_horizon(
+                    cfg, statics, state, horizon_cap))
+            blocked = started & visible_now
+            if cfg.serving_on:
+                # arrival-envelope bound on queue-threshold crossings, and
+                # stay per-tick while the queue sits over a threshold (the
+                # next tick's sweep WILL move mass): overload IS the event
+                k_quiet = jnp.minimum(k_quiet, srv.serving_crossing_horizon(
+                    cfg, state, statics, horizon_cap))
+                blocked = blocked | srv.serving_trigger(cfg, state)
+            budget = jnp.where(blocked, 0, k_quiet)
+            queued, running, util = _counts_and_util(state, statics)
 
         def peek_stop(s, t_next):
             # authoritative, side-effect free: an event tick is NOT
@@ -1081,8 +1123,9 @@ def make_macro_step(
                 s, a, i, _ = c
                 t_next = s.t + cfg.dt
                 stop = peek_stop(s, t_next)
-                p = compute_power(cfg, s._replace(t=t_next), statics,
-                                  use_kernel=use_power_kernel)
+                with jax.named_scope("tick.power"):
+                    p = compute_power(cfg, s._replace(t=t_next), statics,
+                                      use_kernel=use_power_kernel)
                 was_hot = s.rack_outlet_c >= trip_c
                 s, a, i = commit(s, a, i, stop, t_next, p)
                 go = ~stop
@@ -1092,19 +1135,21 @@ def make_macro_step(
                     go &= ~srv.serving_trigger(cfg, s)
                 return (s, a, i, go)
 
-            state, acc, took, _ = jax.lax.while_loop(
-                lambda c: c[3] & (c[2] < budget), body,
-                (state, acc, jnp.int32(0), budget > 0))
+            with jax.named_scope("macro.fast"):
+                state, acc, took, _ = jax.lax.while_loop(
+                    lambda c: c[3] & (c[2] < budget), body,
+                    (state, acc, jnp.int32(0), budget > 0))
             return state, acc, 1 + took
 
         # large configs: per-segment job->node count matrix + chunked
         # power precompute; the inner tick body is then O(scalar) + the
         # O(J) progress/peek ops
-        J, K = state.placement.shape
-        valid = state.placement >= 0
-        safe = jnp.where(valid, state.placement, 0)
-        cnt = jnp.zeros((J, N), jnp.float32).at[
-            jnp.arange(J)[:, None], safe].add(valid.astype(jnp.float32))
+        with jax.named_scope("macro.count_matrix"):
+            J, K = state.placement.shape
+            valid = state.placement >= 0
+            safe = jnp.where(valid, state.placement, 0)
+            cnt = jnp.zeros((J, N), jnp.float32).at[
+                jnp.arange(J)[:, None], safe].add(valid.astype(jnp.float32))
 
         def inner_body(c):
             s, a, i, j, _, chk = c
@@ -1129,9 +1174,10 @@ def make_macro_step(
                 (s, a, i, jnp.int32(0), go, chk))
             return (s, a, i, go)
 
-        state, acc, took, _ = jax.lax.while_loop(
-            lambda c: c[3] & (c[2] < budget), outer_body,
-            (state, acc, jnp.int32(0), budget > 0))
+        with jax.named_scope("macro.fast"):
+            state, acc, took, _ = jax.lax.while_loop(
+                lambda c: c[3] & (c[2] < budget), outer_body,
+                (state, acc, jnp.int32(0), budget > 0))
         return state, acc, 1 + took
 
     return macro_step
