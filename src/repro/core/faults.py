@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.sim import SimConfig
-from repro.core.state import FAILED, NRES, QUEUED, RUNNING, SimState, Statics
+from repro.core.state import FAILED, QUEUED, RUNNING, SimState, Statics
 from repro.scenarios.events import (
     next_outage_event,
     outage_down,
@@ -117,19 +117,19 @@ def release_jobs(free: jax.Array, state: SimState,
                  mask: jax.Array) -> jax.Array:
     """Add back resources of jobs in `mask` (J,) to the free pool.
 
-    Routed through ``power.scatter_add_nodes``: small configs get the
-    dense one-hot contraction (under vmap the XLA scatter-add runs a
-    generic per-env scatter loop on CPU, while the contraction is one
-    batched matmul — this sits on the RL-rollout hot path, every
-    completion sweep of every sub-step of every env)."""
+    Routed through ``power.scatter_add_nodes`` with the unmasked jobs'
+    slots dropped: small configs get the dense one-hot contraction (under
+    vmap the XLA scatter-add runs a generic per-env scatter loop on CPU,
+    while the contraction is one batched matmul — this sits on the
+    RL-rollout hot path, every completion sweep of every sub-step of every
+    env); larger ones the scatter-add on the CPU and, on accelerators,
+    ``req @ job_node_counts`` (their scatter-add is a serial sort-and-sum
+    over every slot of the job table). Integer requests add back exactly
+    on every path."""
     from repro.core.power import scatter_add_nodes
 
-    place = state.placement
-    valid = (place >= 0) & mask[:, None]
-    amounts = state.req[:, :, None] * valid[None, :, :]      # (R,J,K)
-    ids = jnp.where(valid, place, -1)
-    return scatter_add_nodes(ids.reshape(-1), amounts.reshape(NRES, -1),
-                             free.shape[1], base=free)
+    place = jnp.where(mask[:, None], state.placement, -1)
+    return scatter_add_nodes(place, state.req, free.shape[1], base=free)
 
 
 def next_fault_event(cfg: SimConfig, state: SimState, statics: Statics,

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.sim import SimConfig
-from repro.core.state import RUNNING, NRES, SimState, Statics
+from repro.core.state import RUNNING, SimState, Statics
 from repro.kernels.ref import node_power_ref
 from repro.scenarios.signals import eval_signal
 
@@ -57,12 +57,23 @@ def job_utilization(cfg: SimConfig, state: SimState, statics: Statics):
     return cpu * running, gpu * running
 
 
-# Dense one-hot budget for job->node reductions: vmapped XLA scatter-adds
-# are slow on CPU (generic scatter loop per env), while a (slots, N)
-# one-hot contraction runs as one batched matmul — the same trick the
-# Pallas power-scatter kernel plays on the MXU. Used whenever the one-hot
-# stays under this many elements (~0.5 MB f32); bigger configs (tx_gaia)
-# keep the memory-free scatter.
+# Job->node reductions (release into ``free``, per-node loads, the macro
+# engine's count matrix) add a per-job amount at each of the job's node
+# slots: J*K slots onto N nodes. Three forms, chosen from the shapes and
+# from the platform the program is lowered for:
+# - the dense (slots, N) one-hot contraction while the one-hot stays under
+#   DENSE_SCATTER_ELEMS (~0.5 MB f32): under vmap the XLA scatter-add runs
+#   a generic per-env scatter loop on the CPU, while the contraction is one
+#   batched matmul (the trick the Pallas power-scatter kernel plays on the
+#   MXU). The budget was tuned on the CPU;
+# - above it, on the CPU: the memory-free XLA scatter-add over the slots,
+#   cheap there (tens of microseconds for TX-GAIA's 32,768);
+# - above it, on accelerators: the per-job node-count matrix
+#   (``job_node_counts``, one fused compare-and-sum over K) contracted with
+#   the per-job amounts. The TPU compiler turns a scatter-add into a sort
+#   of the slot ids and a serial segmented sum: 285-325 us per scatter at
+#   TX-GAIA size (512 x 64 slots, 928 nodes) on one TPU v5e, against 14 us
+#   for a build and under 2 for its contraction.
 DENSE_SCATTER_ELEMS = 131072
 
 
@@ -78,25 +89,84 @@ def node_onehot(place_flat: jax.Array, n_nodes: int) -> jax.Array:
             ).astype(jnp.float32)
 
 
-def scatter_add_nodes(ids: jax.Array, amounts: jax.Array, n_nodes: int,
-                      base: jax.Array | None = None) -> jax.Array:
+def job_node_counts(placement: jax.Array, n_nodes: int) -> jax.Array:
+    """(J, N) f32 count of each job's slots on each node,
+    ``cnt[j, n] = sum_k [placement[j, k] == n]`` (invalid slots, id < 0,
+    count nowhere). One compare and sum over K that the compiler fuses:
+    no scatter, and no (J, K, N) tensor in memory. Counts are small
+    integers, exact in f32."""
+    with jax.named_scope("tick.node_counts"):
+        hit = placement[..., None] == jnp.arange(n_nodes)
+        return jnp.sum(hit.astype(jnp.float32), axis=-2)
+
+
+def _scatter_node_counts(placement: jax.Array, n_nodes: int) -> jax.Array:
+    """``job_node_counts`` built by a scatter-add over the J*K slots (the
+    CPU's form: a 32,768-slot scatter there is cheaper than the compare)."""
+    J = placement.shape[0]
+    valid = placement >= 0
+    safe = jnp.where(valid, placement, 0)
+    return jnp.zeros((J, n_nodes), jnp.float32).at[
+        jnp.arange(J)[:, None], safe].add(valid.astype(jnp.float32))
+
+
+def node_counts(placement: jax.Array, n_nodes: int) -> jax.Array:
+    """``job_node_counts`` in the form that is cheap on the platform the
+    program is lowered for (the scatter on the CPU, the fused compare
+    elsewhere); the counts are exact integers either way."""
+    return jax.lax.platform_dependent(
+        placement,
+        cpu=lambda p: _scatter_node_counts(p, n_nodes),
+        default=lambda p: job_node_counts(p, n_nodes))
+
+
+def scatter_add_nodes(placement: jax.Array, amounts: jax.Array,
+                      n_nodes: int, base: jax.Array | None = None
+                      ) -> jax.Array:
     """The job-table -> per-node reduction shared by the power chain
-    (``node_loads``) and the release path (``sim._release``): add
-    ``amounts`` (..., S) at node ``ids`` (S,) onto ``base`` (..., n_nodes)
-    (zeros when None); ids < 0 drop. Under the ``use_dense_scatter``
-    budget this is the dense one-hot contraction at ``Precision.HIGHEST``
-    (exact f32 — TPU bf16 / GPU TF32 matmul defaults would round, and the
-    result feeds free-pool feasibility checks); larger configs keep the
-    memory-free XLA scatter-add."""
-    if use_dense_scatter(ids.shape[0], n_nodes):
-        dense = jnp.matmul(amounts, node_onehot(ids, n_nodes),
+    (``node_loads``) and the release path (``faults.release_jobs``): add
+    the per-job ``amounts`` (..., J) at every node slot of ``placement``
+    (J, K) onto ``base`` (..., n_nodes) (zeros when None); slots < 0 drop.
+
+    Under the ``use_dense_scatter`` budget this is the dense one-hot
+    contraction. Above it the CPU keeps the memory-free XLA scatter-add
+    over the slots, and every other platform contracts the amounts with
+    ``job_node_counts`` (``_count_path``), because its scatter-add is a
+    serial sort-and-sum over all J*K slots. Contractions run at
+    ``Precision.HIGHEST``: exact f32 (TPU bf16 / GPU TF32 matmul defaults
+    would round, and the result feeds free-pool feasibility checks), so
+    integer requests sum exactly into ``free`` on every path."""
+    J, K = placement.shape
+    if use_dense_scatter(J * K, n_nodes):
+        slots = amounts[..., :, None] * (placement >= 0)
+        dense = jnp.matmul(slots.reshape(amounts.shape[:-1] + (J * K,)),
+                           node_onehot(placement.reshape(-1), n_nodes),
                            precision=jax.lax.Precision.HIGHEST)
         return dense if base is None else base + dense
     if base is None:
         base = jnp.zeros(amounts.shape[:-1] + (n_nodes,), amounts.dtype)
+    return jax.lax.platform_dependent(
+        placement, amounts, base, cpu=_scatter_path, default=_count_path)
+
+
+def _scatter_path(placement, amounts, base):
+    """``scatter_add_nodes`` above the dense budget, as an XLA scatter-add
+    over the J*K slots (the CPU's form)."""
+    ids = placement.reshape(-1)
+    slots = (amounts[..., :, None] * (placement >= 0)).reshape(
+        amounts.shape[:-1] + ids.shape)
     safe = jnp.where(ids >= 0, ids, 0)
-    return base.at[..., safe].add(
-        jnp.where(ids >= 0, amounts, 0.0), mode="drop")
+    return base.at[..., safe].add(jnp.where(ids >= 0, slots, 0.0),
+                                  mode="drop")
+
+
+def _count_path(placement, amounts, base):
+    """``scatter_add_nodes`` above the dense budget, as (..., J) amounts
+    contracted with the (J, N) node-count matrix (the accelerators'
+    form)."""
+    cnt = job_node_counts(placement, base.shape[-1])
+    return base + jnp.matmul(amounts, cnt,
+                             precision=jax.lax.Precision.HIGHEST)
 
 
 def placement_amounts(state: SimState, cpu_util: jax.Array,
@@ -116,19 +186,15 @@ def placement_amounts(state: SimState, cpu_util: jax.Array,
 
 def node_loads(cfg: SimConfig, state: SimState, statics: Statics,
                cpu_util: jax.Array, gpu_util: jax.Array):
-    """Scatter per-job utilized resources onto nodes.
+    """Reduce per-job utilized resources onto nodes.
 
     Returns (cpu_load, gpu_load) as *fractions of node capacity* in [0,1].
     """
     N = statics.capacity.shape[1]
-    place = state.placement                       # (J,K)
-    w = (place >= 0).astype(jnp.float32)
-    # utilized absolute resources contributed per placement slot
-    cpu_abs = (state.req[0][:, None] * cpu_util[:, None]) * w
-    gpu_abs = (state.req[1][:, None] * gpu_util[:, None]) * w
+    # utilized absolute resources of each job, on each of its nodes
     loads = scatter_add_nodes(
-        place.reshape(-1),
-        jnp.stack([cpu_abs.reshape(-1), gpu_abs.reshape(-1)]), N)
+        state.placement,
+        jnp.stack([state.req[0] * cpu_util, state.req[1] * gpu_util]), N)
     cpu_node, gpu_node = loads[0], loads[1]
     cpu_frac = jnp.clip(cpu_node / jnp.maximum(statics.capacity[0], 1e-6), 0, 1)
     gpu_frac = jnp.clip(gpu_node / jnp.maximum(statics.capacity[1], 1e-6), 0, 1)

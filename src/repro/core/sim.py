@@ -50,6 +50,7 @@ from repro.core.power import (
     PowerOut,
     compute_power,
     job_utilization,
+    node_counts,
     power_from_fracs,
     use_dense_scatter,
 )
@@ -1145,11 +1146,7 @@ def make_macro_step(
         # power precompute; the inner tick body is then O(scalar) + the
         # O(J) progress/peek ops
         with jax.named_scope("macro.count_matrix"):
-            J, K = state.placement.shape
-            valid = state.placement >= 0
-            safe = jnp.where(valid, state.placement, 0)
-            cnt = jnp.zeros((J, N), jnp.float32).at[
-                jnp.arange(J)[:, None], safe].add(valid.astype(jnp.float32))
+            cnt = node_counts(state.placement, N)
 
         def inner_body(c):
             s, a, i, j, _, chk = c

@@ -11,7 +11,9 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ from repro.kernels.rack_thermal import rack_thermal_pallas
 from repro.utils.hlo import tpu_kernel_calls
 
 V5E_HBM_BYTES = 16 * 2**30
+SLOTS = 512 * 64                    # tx_gaia(): max_jobs x max_nodes_per_job
 RECT = dict(rect_peak=0.965, rect_load=0.55, rect_curv=0.12, conv_eff=0.975)
 
 
@@ -63,6 +66,54 @@ def _compile(fn, *args):
             + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
     assert used < V5E_HBM_BYTES, f"{used} bytes do not fit a v5e"
     return compiled
+
+
+INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _elems(shape):
+    """Elements of the largest array in an HLO shape (tuples included)."""
+    return max((math.prod(int(d) for d in dims.split(",") if d)
+                for dims in ARRAY.findall(shape)), default=0)
+
+
+def _instructions(text):
+    """(name, shape, opcode, operand names) of each HLO instruction."""
+    for line in text.splitlines():
+        m = INSTR.match(line)
+        if not m:
+            continue
+        rest, depth, end = line[m.end():], 0, 0
+        if rest.startswith("("):          # a tuple shape nests parentheses
+            for end, ch in enumerate(rest, 1):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+        else:
+            end = rest.find(" ")
+        shape = rest[:end]
+        op = re.match(r"\s*([\w\-]+)\(([^)]*)\)", rest[end:])
+        if op:
+            yield (m.group(1), shape, op.group(1),
+                   [a.strip().lstrip("%") for a in op.group(2).split(",")])
+
+
+def slot_reductions(text, n_slots):
+    """Scatters and sorts of the optimised HLO ``text`` that touch
+    ``n_slots`` or more elements: the job-table -> node scatter-adds, and
+    the sorts a TPU compiler rewrites them into."""
+    ops = list(_instructions(text))
+    shapes = {name: shape for name, shape, _, _ in ops}
+    found = []
+    for name, _, opcode, operands in ops:
+        if opcode not in ("scatter", "sort"):
+            continue
+        sizes = [_elems(shapes[name])] + [_elems(shapes.get(a, ""))
+                                          for a in operands]
+        if max(sizes) >= n_slots:
+            found.append(f"{opcode} {name}")
+    return found
 
 
 def test_power_scatter_compiles_at_tx_gaia_width(one_chip):
@@ -114,6 +165,21 @@ def test_tx_gaia_step_compiles(one_chip, kernels, monkeypatch):
     c = _compile(step, _sds(statics, one_chip), _sds(state, one_chip))
     want = {"power_scatter": 1, "rack_thermal": 1} if kernels else {}
     assert tpu_kernel_calls(c.as_text()) == want
+    # release and loads take the node-count matrix on the chip
+    assert slot_reductions(c.as_text(), SLOTS) == []
+    assert "tick.node_counts" in c.as_text()
+
+
+def test_tx_gaia_step_keeps_the_scatter_on_cpu():
+    """The same step lowered for the CPU keeps the slot scatter-adds
+    (cheap there), which ``slot_reductions`` finds."""
+    cfg = tx_gaia()
+    statics = build_statics(cfg)
+    state = init_state(cfg, statics, jax.random.key(0))
+    text = jax.jit(lambda st, s: make_step(cfg, st, "fcfs")(
+        s, jnp.int32(-1))).lower(statics, state).compile().as_text()
+    assert len(slot_reductions(text, SLOTS)) >= 2
+    assert "tick.node_counts" not in text
 
 
 def test_tx_gaia_macro_step_compiles(one_chip):
@@ -125,5 +191,8 @@ def test_tx_gaia_macro_step_compiles(one_chip):
     def macro(st, s, a):
         return make_macro_step(cfg, st, "fcfs")(s, a, 3600)
 
-    _compile(macro, _sds(statics, one_chip), _sds(state, one_chip),
-             _sds(acc, one_chip))
+    c = _compile(macro, _sds(statics, one_chip), _sds(state, one_chip),
+                 _sds(acc, one_chip))
+    # release, loads and the count matrix: no slot scatter or sort
+    assert slot_reductions(c.as_text(), SLOTS) == []
+    assert "tick.node_counts" in c.as_text()
