@@ -138,7 +138,10 @@ class Phase:
 
 # ------------------------------------------------------------- comparison
 def _host(x):
-    if jnp.issubdtype(x.dtype, jax.dtypes.prng_key):
+    """Host copy of a leaf; an absent field (None: a subsystem that is
+    off, or the streamed-admission carry on the resident path) stays an
+    object array that compares equal only to another absent one."""
+    if x is not None and jnp.issubdtype(x.dtype, jax.dtypes.prng_key):
         x = jax.random.key_data(x)
     return np.asarray(jax.device_get(x))
 

@@ -218,7 +218,7 @@ def run_episode_snapshotted(
         snapshot_dir = resume_from
     fp = run_fingerprint("episode", cfg, scheduler, statics, state,
                          n_steps, kw)
-    acc = sim._telem_zero(cfg.resilience_on, cfg.serving_on)
+    acc = sim.telem_zero(cfg, statics)
     ticks = 0
     if resume_from is not None:
         tree, ticks = _restore_latest(

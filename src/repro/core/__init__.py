@@ -53,8 +53,10 @@ from repro.core.sim import (
     make_step,
     quiet_horizon,
     run_episode,
+    run_segment,
     summary,
     summary_columns,
+    telem_zero,
 )
 from repro.core.state import (
     DONE,
@@ -64,7 +66,10 @@ from repro.core.state import (
     RUNNING,
     SimState,
     Statics,
+    Stream,
+    Trace,
     build_statics,
     init_state,
     load_jobs,
+    trace_records,
 )

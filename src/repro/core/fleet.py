@@ -273,6 +273,11 @@ def _fleet_inputs(statics, state, scheduler, scenarios, policies,
     """``run_fleet``'s argument checks and replica batching: (scheduler,
     batched scenarios, batched policies or None, replica-batched state,
     per-replica keys)."""
+    if statics.trace is not None:
+        raise ConfigError(
+            "run_fleet keeps every replica's trace resident in its job "
+            f"table; this trace of {statics.trace.submit_t.shape[0]} jobs "
+            "streams through it: replay it with run_episode/run_segment")
     if policies is not None and scheduler is not None:
         raise ConfigError(
             f"both scheduler={scheduler!r} and policies= given — policies "

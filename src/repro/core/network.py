@@ -17,9 +17,14 @@ def congestion_slowdown(cfg: SimConfig, state: SimState, statics: Statics):
     """Returns (per-job progress rate in (0,1], network load fraction)."""
     running = (state.jstate == RUNNING).astype(jnp.float32)
     # banked (W, J) traffic table: gather this replica's row through the
-    # traced workload id (see Statics docstring)
-    net_tx = (statics.net_tx if statics.net_tx.ndim == 1
-              else statics.net_tx[state.workload])
+    # traced workload id; a streamed trace's (n,) table through each
+    # slot's trace id (see Statics docstring)
+    if statics.trace is not None:
+        net_tx = statics.net_tx[jnp.maximum(state.stream.tid, 0)]
+    elif statics.net_tx.ndim == 1:
+        net_tx = statics.net_tx
+    else:
+        net_tx = statics.net_tx[state.workload]
     # jobs spanning n nodes inject n * net_tx GB/s into the fabric
     tx = net_tx * state.n_nodes.astype(jnp.float32) * running
     load = jnp.sum(tx) / jnp.maximum(cfg.bisection_gbps, 1e-6)

@@ -42,12 +42,17 @@ def job_utilization(cfg: SimConfig, state: SimState, statics: Statics):
     through the traced ``state.workload`` id — one J-element gather per
     step, identical cost to the unbatched path, and the bank itself is
     never copied per env (the lightweight-state rollout engine's key
-    invariant)."""
+    invariant). A streamed trace's bank has a row per trace job, read
+    through each slot's trace id."""
     running = (state.jstate == RUNNING).astype(jnp.float32)
     age = jnp.maximum(state.t - state.start_t, 0.0)
     q = statics.cpu_trace.shape[-1]
     qi = jnp.clip((age / cfg.trace_quanta).astype(jnp.int32), 0, q - 1)
-    if statics.cpu_trace.ndim == 3:
+    if statics.trace is not None:
+        rows = jnp.maximum(state.stream.tid, 0)
+        cpu = statics.cpu_trace[rows, qi]
+        gpu = statics.gpu_trace[rows, qi]
+    elif statics.cpu_trace.ndim == 3:
         j = jnp.arange(state.jstate.shape[0])
         cpu = statics.cpu_trace[state.workload, j, qi]
         gpu = statics.gpu_trace[state.workload, j, qi]

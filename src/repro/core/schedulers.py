@@ -124,19 +124,32 @@ def _masked_argmin(score: jax.Array, mask: jax.Array) -> jax.Array:
     return jnp.where(jnp.any(mask), idx, -1)
 
 
+def _pick(state: SimState, score: jax.Array, mask: jax.Array) -> jax.Array:
+    """The masked job of least ``score``, ties to the earliest in trace
+    order. On the resident path slot order is trace order; a streamed
+    trace reuses slots, so there ties go to the least trace id."""
+    if state.stream is None:
+        return _masked_argmin(score, mask)
+    s = jnp.where(mask, score, BIG)
+    tie = mask & (s == jnp.min(s))
+    last = jnp.iinfo(jnp.int32).max
+    idx = jnp.argmin(jnp.where(tie, state.stream.tid, last))
+    return jnp.where(jnp.any(mask), idx, -1)
+
+
 def select_fcfs(cfg: SimConfig, state: SimState, statics: Statics,
                 node_mask: jax.Array | None = None) -> jax.Array:
-    return _masked_argmin(state.submit_t, queued_mask(state))
+    return _pick(state, state.submit_t, queued_mask(state))
 
 
 def select_sjf(cfg: SimConfig, state: SimState, statics: Statics,
                node_mask: jax.Array | None = None) -> jax.Array:
-    return _masked_argmin(state.dur_est, queued_mask(state))
+    return _pick(state, state.dur_est, queued_mask(state))
 
 
 def select_priority(cfg: SimConfig, state: SimState, statics: Statics,
                     node_mask: jax.Array | None = None) -> jax.Array:
-    return _masked_argmin(-state.priority, queued_mask(state))
+    return _pick(state, -state.priority, queued_mask(state))
 
 
 def select_replay(cfg: SimConfig, state: SimState, statics: Statics,
@@ -144,7 +157,7 @@ def select_replay(cfg: SimConfig, state: SimState, statics: Statics,
     """Replay: dispatch in recorded start order — priority carries the
     recorded start time; a job becomes eligible once t >= recorded start."""
     m = queued_mask(state) & (state.priority <= state.t)
-    return _masked_argmin(state.priority, m)
+    return _pick(state, state.priority, m)
 
 
 def shadow_time(cfg: SimConfig, state: SimState, statics: Statics,
@@ -207,7 +220,7 @@ def select_easy(cfg: SimConfig, state: SimState, statics: Statics,
             m = queued_mask(state) & fits_now_mask(state, node_mask)
             fits_window = (state.t + state.dur_est) <= t_sh
             not_head = jnp.arange(m.shape[0]) != head
-            cand = _masked_argmin(state.submit_t, m & fits_window & not_head)
+            cand = _pick(state, state.submit_t, m & fits_window & not_head)
             return cand
 
         return jax.lax.cond(head_fits, lambda _: head, backfill, None)

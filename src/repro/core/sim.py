@@ -17,9 +17,10 @@ serves the entire selection x placement grid.
 Step order (matches RAPS' fixed-dt loop):
   1. node failures / repairs (MTBF process)       [optional]
   2. job completions -> free resources, stats
-  3. scheduling: up to `starts_per_step` dispatch attempts via the policy
-  4. progress running jobs (network-congestion-aware rate)
-  5. power chain + energy/carbon/stat accumulation
+  3. admission of the next trace jobs into freed slots [streamed traces]
+  4. scheduling: up to `starts_per_step` dispatch attempts via the policy
+  5. progress running jobs (network-congestion-aware rate)
+  6. power chain + energy/carbon/stat accumulation
 
 Each stage of the tick and of the macro step is traced under a
 ``jax.named_scope`` (``tick.*``, ``macro.*``; docs/performance.md,
@@ -59,6 +60,7 @@ from repro.scenarios.signals import eval_signal
 from repro.core.state import (
     DONE,
     EMPTY,
+    FAILED,
     NRES,
     QUEUED,
     RUNNING,
@@ -106,6 +108,10 @@ class StepOut(NamedTuple):
     srv_queue_len: jax.Array | None = None   # post-flow queued mass
     srv_active_nodes: jax.Array | None = None
     srv_lat_hist_step: jax.Array | None = None  # (8,) per-tick histogram
+    # streamed admission (``_admit``); None on the resident path and on
+    # the macro engine's fast ticks, which admit nothing
+    admitted_step: jax.Array | None = None   # trace jobs admitted
+    overflow_step: jax.Array | None = None   # 1.0 if a pending job is due
 
 
 def _scoped(name: str):
@@ -444,6 +450,69 @@ def _complete_jobs(cfg: SimConfig, state: SimState) -> Tuple[SimState, jax.Array
     return state, n_done
 
 
+def _admit(state: SimState, statics: Statics):
+    """Streamed admission: every slot that holds no live job (DONE,
+    FAILED, EMPTY) takes the next trace job, in trace order, as QUEUED;
+    the job it held leaves its outcome in ``stream``'s record. Returns
+    (state, jobs admitted, overflow): overflow is 1.0 when a trace job
+    whose submit time has passed is still outside the table, which can
+    only happen with every slot holding a live job."""
+    tr, st = statics.trace, state.stream
+    n = tr.submit_t.shape[0]
+    free = ((state.jstate == DONE) | (state.jstate == FAILED)
+            | (state.jstate == EMPTY))
+    new = st.cursor + jnp.cumsum(free.astype(jnp.int32)) - 1
+    take = free & (new < n)
+    g = jnp.where(take, new, 0)
+    gone = jnp.where(take & (st.tid >= 0), st.tid, n)   # n: dropped
+    cursor = st.cursor + jnp.sum(take.astype(jnp.int32))
+
+    def col(old, trace_col):
+        return jnp.where(take, trace_col[g], old)
+
+    state = state._replace(
+        jstate=jnp.where(take, QUEUED, state.jstate),
+        submit_t=col(state.submit_t, tr.submit_t),
+        start_t=jnp.where(take, 0.0, state.start_t),
+        end_t=jnp.where(take, 0.0, state.end_t),
+        dur_est=col(state.dur_est, tr.dur),
+        work_left=col(state.work_left, tr.dur),
+        n_nodes=col(state.n_nodes, tr.n_nodes),
+        req=jnp.where(take[None, :], tr.req[:, g], state.req),
+        part=col(state.part, tr.part),
+        priority=col(state.priority, tr.priority),
+        n_failures=jnp.where(take, 0, state.n_failures),
+        ckpt_interval=col(state.ckpt_interval, tr.ckpt_interval),
+        stream=st._replace(
+            tid=jnp.where(take, new, st.tid), cursor=cursor,
+            jstate=st.jstate.at[gone].set(state.jstate, mode="drop"),
+            start_t=st.start_t.at[gone].set(state.start_t, mode="drop"),
+            end_t=st.end_t.at[gone].set(state.end_t, mode="drop")),
+    )
+    admitted = jnp.sum(take).astype(jnp.float32)
+    return state, admitted, _overflowing(statics, state).astype(jnp.float32)
+
+
+def _overflowing(statics: Statics, state: SimState) -> jax.Array:
+    """A due trace job is outside the table: every tick counts an overflow
+    until a slot frees, so none of them may be fast-forwarded."""
+    return statics.trace.due[state.stream.cursor] <= state.t
+
+
+def _check_stream(statics: Statics, state: SimState) -> None:
+    """Loud error when a streamed trace meets a resident state or the
+    other way round, or the two hold traces of different lengths."""
+    from repro.utils.errors import ConfigError
+
+    trace, stream = statics.trace, state.stream
+    if (trace is None) != (stream is None) or (
+            trace is not None
+            and trace.submit_t.shape != stream.jstate.shape):
+        raise ConfigError(
+            "statics and state disagree on the streamed trace: build both "
+            "from the same jobs (build_statics(..., jobs=), load_jobs)")
+
+
 def _try_start(cfg: SimConfig, state: SimState, job: jax.Array,
                place_fn) -> SimState:
     """Attempt to place & start `job` via the placement stage `place_fn`
@@ -511,6 +580,7 @@ def make_step(
         raise KeyError(f"unknown placement {placement}")
     tail = _make_tail(cfg, statics, reward_weights,
                       use_thermal_kernel=use_thermal_kernel)
+    streamed = statics.trace is not None
 
     if cfg.thermal_enabled or cfg.resilience_on:
         # dispatch-only gates folded into node_up through ONE seam, so
@@ -566,6 +636,10 @@ def make_step(
             shed_now = dropped_now = retried_now = None
         with jax.named_scope("tick.complete"):
             state, n_done = _complete_jobs(cfg, state)
+        if streamed:
+            _check_stream(statics, state)
+            with jax.named_scope("tick.admit"):
+                state, admitted, overflow = _admit(state, statics)
 
         # --- dispatch
         with jax.named_scope("tick.dispatch"):
@@ -618,8 +692,12 @@ def make_step(
         with jax.named_scope("tick.load"):
             rate, net_load = congestion_slowdown(cfg, state, statics)
             queued, running, util = _counts_and_util(state, statics)
-        return tail(state, p, rate, net_load, n_done, queued, running, util,
-                    killed_now, lost_now, shed_now, dropped_now, retried_now)
+        state, out = tail(state, p, rate, net_load, n_done, queued, running,
+                          util, killed_now, lost_now, shed_now, dropped_now,
+                          retried_now)
+        if streamed:
+            out = out._replace(admitted_step=admitted, overflow_step=overflow)
+        return state, out
 
     return step
 
@@ -676,14 +754,25 @@ class TelemetrySummary(NamedTuple):
     # path. Per-tick runs have macro_steps == n_steps (skip ratio 1); a
     # macro run's speedup potential is n_steps / macro_steps.
     macro_steps: jax.Array
+    # streamed admission (a trace longer than the job table); None on the
+    # resident path. ``admitted``: trace jobs that entered the table;
+    # ``admit_overflow``: ticks on which a due trace job found no slot
+    # (every slot held a live job), a replay that no longer follows the
+    # trace; ``live_slot_ticks``: slots holding a live (submitted, not
+    # finished) job, summed over full event ticks.
+    admitted: jax.Array | None = None
+    admit_overflow: jax.Array | None = None
+    live_slot_ticks: jax.Array | None = None
 
 
 _SRV_TELEM = ("srv_arrived", "srv_completed", "srv_shed", "srv_dropped",
               "srv_retried", "srv_slo_viol", "srv_lat_sum", "srv_lat_hist")
+_ADMIT_TELEM = ("admitted", "admit_overflow", "live_slot_ticks")
 
 
 def _telem_zero(resilience_on: bool = True,
-                serving_on: bool = False) -> TelemetrySummary:
+                serving_on: bool = False,
+                streamed: bool = False) -> TelemetrySummary:
     z = jnp.float32(0.0)
     acc = TelemetrySummary(*([z] * len(TelemetrySummary._fields)))
     if not resilience_on:
@@ -701,7 +790,16 @@ def _telem_zero(resilience_on: bool = True,
         # same XLA-codegen hazard as killed/lost above: the serving
         # accumulators ride as empty nodes when the plane is off
         acc = acc._replace(**{f: None for f in _SRV_TELEM})
+    if not streamed:
+        acc = acc._replace(**{f: None for f in _ADMIT_TELEM})
     return acc
+
+
+def telem_zero(cfg: SimConfig, statics: Statics) -> TelemetrySummary:
+    """The empty raw accumulator of one replica of ``cfg`` over
+    ``statics`` (``run_segment``'s ``acc`` at an episode's start)."""
+    return _telem_zero(cfg.resilience_on, cfg.serving_on,
+                       streamed=statics.trace is not None)
 
 
 @_scoped("tick.telemetry")
@@ -714,7 +812,9 @@ def _telem_update(acc: TelemetrySummary, out: StepOut,
     # off the addends are constant zeros, but even dead adds perturb XLA's
     # scan-body codegen enough to shift float rounding elsewhere in the
     # step — gating keeps the legacy per-tick program (and its bit-pinned
-    # outputs) intact.
+    # outputs) intact. The admission counters move on the streamed path's
+    # full ticks only, which alone carry ``admitted_step``.
+    full_admit = out.admitted_step is not None
     return TelemetrySummary(
         completed=acc.completed + out.completed_now,
         srv_arrived=acc.srv_arrived + out.srv_arrived_step
@@ -759,6 +859,12 @@ def _telem_update(acc: TelemetrySummary, out: StepOut,
         max_rack_c=jnp.maximum(acc.max_rack_c, out.rack_max_c),
         n_steps=acc.n_steps + 1.0,
         macro_steps=acc.macro_steps + macro_inc,
+        admitted=acc.admitted + out.admitted_step
+        if full_admit else acc.admitted,
+        admit_overflow=acc.admit_overflow + out.overflow_step
+        if full_admit else acc.admit_overflow,
+        live_slot_ticks=acc.live_slot_ticks + out.queue_len + out.running
+        if full_admit else acc.live_slot_ticks,
     )
 
 
@@ -834,6 +940,11 @@ def _horizon_parts(cfg: SimConfig, state: SimState, statics: Statics,
     # submit time is crossed; selection visibility changes with it
     next_t = jnp.min(jnp.where(q & (state.submit_t > t),
                                state.submit_t, _BIG_T))
+    if statics.trace is not None:
+        # a trace job not admitted yet falls due (an overflow, counted on
+        # full ticks only); jobs in the table are covered above
+        due = statics.trace.due[state.stream.cursor]
+        next_t = jnp.minimum(next_t, jnp.where(due > t, due, _BIG_T))
     visible_now = jnp.bool_(False)
     if dispatch_on:
         vis_t = state.submit_t
@@ -896,7 +1007,8 @@ def quiet_horizon(
     The horizon is the min over the next arrival (submit crossing), next
     replay-eligibility crossing, next completion (conservative: assumes
     full-rate progress, minus one tick of float margin), next node repair,
-    next cap-schedule breakpoint, and — with the fault engine on — the
+    next cap-schedule breakpoint, the next submit time of a streamed trace
+    job not admitted yet, and — with the fault engine on — the
     next event-sampled fault-clock crossing / outage-window edge
     (``core.faults.next_fault_event``), clamped to ``max_ticks``.
     Faults are EXACT breakpoints: the clocks are absolute times redrawn
@@ -930,6 +1042,8 @@ def quiet_horizon(
         cfg, state, statics, rate, dispatch_on, replay_gated,
         eligibility_vis, max_ticks)
     blocked = visible_now & ~jnp.asarray(assume_undispatchable)
+    if statics.trace is not None:
+        blocked = blocked | _overflowing(statics, state)
     horizon = jnp.where(blocked, 0, jnp.minimum(k_time, k_complete))
     if cfg.thermal_enabled and dispatch_on:
         horizon = jnp.minimum(horizon, thm.thermal_crossing_horizon(
@@ -1050,12 +1164,21 @@ def make_macro_step(
         )(ts, cpu_frac, gpu_frac)
         return ts, p
 
+    streamed = statics.trace is not None
+
     def macro_step(state: SimState, acc, max_ticks):
         with jax.named_scope("macro.event"):
             was_queued = state.jstate == QUEUED
+            if streamed:
+                tid0 = state.stream.tid
             state, out = step(state, jnp.int32(-1))
             acc = update(acc, out, 1.0)
-            started = jnp.any(was_queued & (state.jstate == RUNNING))
+            now_running = state.jstate == RUNNING
+            started = jnp.any(was_queued & now_running)
+            if streamed:
+                # a slot refilled this tick may have started its new job
+                started = started | jnp.any(
+                    (state.stream.tid != tid0) & now_running)
 
         # --- segment constants (all provably frozen across quiet ticks).
         with jax.named_scope("macro.horizon"):
@@ -1085,6 +1208,8 @@ def make_macro_step(
                 k_quiet = jnp.minimum(k_quiet, thm.thermal_crossing_horizon(
                     cfg, statics, state, horizon_cap))
             blocked = started & visible_now
+            if streamed:
+                blocked = blocked | _overflowing(statics, state)
             if cfg.serving_on:
                 # arrival-envelope bound on queue-threshold crossings, and
                 # stay per-tick while the queue sits over a threshold (the
@@ -1293,7 +1418,7 @@ def run_episode(
 
             s, a, _ = jax.lax.while_loop(
                 wcond, wbody,
-                (state, _telem_zero(cfg.resilience_on, cfg.serving_on),
+                (state, telem_zero(cfg, statics),
                  jnp.int32(0)))
             return s, _telem_finalize(a)
 
@@ -1329,7 +1454,7 @@ def run_episode(
             def go(state):
                 (fs, acc), _ = jax.lax.scan(
                     accum_body,
-                    (state, _telem_zero(cfg.resilience_on, cfg.serving_on)),
+                    (state, telem_zero(cfg, statics)),
                     None, length=n_steps)
                 return fs, _telem_finalize(acc)
         elif telemetry_every <= 1:
@@ -1339,7 +1464,7 @@ def run_episode(
             def window(s, _):
                 (s, acc), _ = jax.lax.scan(
                     accum_body,
-                    (s, _telem_zero(cfg.resilience_on, cfg.serving_on)),
+                    (s, telem_zero(cfg, statics)),
                     None, length=telemetry_every)
                 return s, _telem_finalize(acc)
 
@@ -1375,13 +1500,13 @@ def run_segment(
     un-finalized, so a sequence of segments threaded through
     ``(state, acc)`` reproduces the single-call episode bit-for-bit —
     the host-level primitive snapshot/resume (checkpoint.episode) is
-    built on. Seed ``acc`` with ``_telem_zero(cfg.resilience_on,
-    cfg.serving_on)`` and apply ``_telem_finalize`` once after the last
-    segment. Segment edges clamp the macro fast-forward exactly like
-    ``telemetry_every`` window edges, so job/queue state and the PRNG
-    stream stay bit-identical to the uninterrupted run (the skip-
-    accounting diagnostics ``n_steps``/``macro_steps`` count the forced
-    boundary breakpoints, same as windowed telemetry).
+    built on. Seed ``acc`` with ``telem_zero(cfg, statics)`` and apply
+    ``_telem_finalize`` once after the last segment. Segment edges clamp
+    the macro fast-forward exactly like ``telemetry_every`` window edges,
+    so job/queue state and the PRNG stream stay bit-identical to the
+    uninterrupted run (the skip-accounting diagnostics
+    ``n_steps``/``macro_steps`` count the forced boundary breakpoints,
+    same as windowed telemetry).
 
     The ``REPRO_CHECKIFY=1`` invariant harness instruments eager calls
     per committed step, exactly as in ``run_episode``.
@@ -1445,7 +1570,8 @@ def run_segment(
 
 
 def summary_columns(state: SimState,
-                    telemetry: TelemetrySummary | None = None) -> dict:
+                    telemetry: TelemetrySummary | None = None,
+                    statics: Statics | None = None) -> dict:
     """Column-wise ``summary``: a dict of float64 numpy arrays with one
     entry per replica, from replica-batched final states (leading replica
     axis on every leaf, e.g. ``run_fleet`` output). Also accepts an
@@ -1453,7 +1579,8 @@ def summary_columns(state: SimState,
     special case. ONE device->host transfer covers the whole batch, and
     all per-replica reductions happen as numpy array ops, so
     ``fleet_summary`` on a 1024-replica sweep no longer spends its tail
-    in a host-side Python loop over replicas."""
+    in a host-side Python loop over replicas. A streamed replica's jobs
+    are not all in its table, so its goodput needs ``statics``."""
     s = jax.device_get(state)
     batched = np.ndim(s.t) == 1
 
@@ -1495,8 +1622,20 @@ def summary_columns(state: SimState,
     # kills destroyed (since-last-checkpoint for retries, whole jobs for
     # terminal failures). goodput_frac = useful / (useful + lost) — the
     # fraction of delivered node-seconds that produced finished jobs.
-    useful = reduce_tail(
-        (np.asarray(s.jstate) == DONE) * f(s.dur_est) * f(s.n_nodes))
+    if s.stream is None:
+        useful = reduce_tail(
+            (np.asarray(s.jstate) == DONE) * f(s.dur_est) * f(s.n_nodes))
+    else:
+        if statics is None:
+            from repro.utils.errors import ConfigError
+
+            raise ConfigError("a streamed replica's summary needs its "
+                              "statics: summary(state, telemetry, statics)")
+        from repro.core.state import trace_records
+
+        tr = jax.device_get(statics.trace)
+        useful = np.sum((trace_records(s)["state"] == DONE)
+                        * f(tr.dur) * f(tr.n_nodes))
     lost = f(s.lost_node_s)
     cols["lost_node_seconds"] = lost
     cols["jobs_failed_terminal"] = f(s.n_failed)
@@ -1546,8 +1685,9 @@ def summary_columns(state: SimState,
 
 
 def summary(state: SimState,
-            telemetry: TelemetrySummary | None = None) -> dict:
+            telemetry: TelemetrySummary | None = None,
+            statics: Statics | None = None) -> dict:
     """Scalar episode summary of one (unbatched) final state — the 0-d
     special case of ``summary_columns``."""
     return {k: float(v)
-            for k, v in summary_columns(state, telemetry).items()}
+            for k, v in summary_columns(state, telemetry, statics).items()}

@@ -1,9 +1,16 @@
 """Simulator state: fixed-shape pytrees so the whole datacenter twin is a
 pure `step(state, action) -> state` function under jit/vmap/scan.
 
-Job lifecycle: EMPTY -> QUEUED -> RUNNING -> DONE (slot then reusable),
-plus the terminal FAILED state for jobs whose retry budget is exhausted
+Job lifecycle: EMPTY -> QUEUED -> RUNNING -> DONE, plus the terminal
+FAILED state for jobs whose retry budget is exhausted
 (``cfg.max_job_retries``; see ``core.faults``).
+
+A trace longer than the job table streams through it: ``Statics.trace``
+holds the whole trace, and each tick's admission stage (``core.sim``)
+refills DONE and FAILED slots with the next trace jobs, in trace order.
+``SimState.stream`` carries the slots' trace ids, the cursor and the
+outcome of every job that has left the table (``trace_records``). A trace
+that fits the table takes the resident path, where both are ``None``.
 """
 
 from __future__ import annotations
@@ -21,6 +28,34 @@ EMPTY, QUEUED, RUNNING, DONE, FAILED = 0, 1, 2, 3, 4
 NRES = 3  # cpu cores, gpus, mem_gb
 
 
+class Trace(NamedTuple):
+    """The job columns of a streamed trace, in trace order, on the device
+    (``load_jobs``' columns, one entry per trace job)."""
+
+    submit_t: jax.Array        # (n,)
+    dur: jax.Array             # (n,)
+    n_nodes: jax.Array         # (n,) int32
+    req: jax.Array             # (NRES, n)
+    part: jax.Array            # (n,) int32
+    priority: jax.Array        # (n,)
+    ckpt_interval: jax.Array   # (n,)
+    # due[c] = min(submit_t[c:]), inf at c = n: the earliest submit time
+    # among the jobs the cursor has not admitted yet
+    due: jax.Array             # (n + 1,)
+
+
+class Stream(NamedTuple):
+    """Streamed-admission carry of one replica."""
+
+    tid: jax.Array             # (J,) int32 trace id of each slot's job
+    cursor: jax.Array          # int32: the next trace job to admit
+    # outcome of each trace job as it left the table; the table holds
+    # the newer outcome of every job still in it (``trace_records``)
+    jstate: jax.Array          # (n,) int32
+    start_t: jax.Array         # (n,)
+    end_t: jax.Array           # (n,)
+
+
 class Statics(NamedTuple):
     """Per-node constants + telemetry bank; NOT carried through the scan.
 
@@ -32,7 +67,11 @@ class Statics(NamedTuple):
       bank serves every vmapped replica/env, and each ``SimState`` selects
       its slice through the traced ``workload`` id. Trace lookups
       (``core.power.job_utilization``, ``core.network``) gather through the
-      id, so per-env state stays O(sim), not O(bank).
+      id, so per-env state stays O(sim), not O(bank);
+    - streamed — (n, Q) / (n,) with more rows than the job table has
+      slots: one row per trace job, gathered through the slot's trace id
+      (``SimState.stream.tid``), and ``trace`` holds the trace's job
+      columns.
     """
 
     capacity: jax.Array        # (NRES, N)
@@ -54,6 +93,8 @@ class Statics(NamedTuple):
     net_tx: jax.Array          # (J,) GB/s per job, or (W, J) banked
     # grid context: carbon/price/wetbulb signals + power-cap events
     scenario: Scenario
+    # the whole trace when it streams through the table; None otherwise
+    trace: Trace | None = None
 
 
 class SimState(NamedTuple):
@@ -138,14 +179,23 @@ class SimState(NamedTuple):
     # Scalar int32 — O(1) per env, vs. the O(J*Q) per-env bank copy the
     # pre-bank-indexed env carried.
     workload: jax.Array
+    # streamed admission (trace longer than the table); None otherwise,
+    # so the resident program carries nothing of it
+    stream: Stream | None = None
 
 
 def build_statics(
     cfg: SimConfig,
     trace_bank: Dict[str, Any] | None = None,
     scenario: Scenario | None = None,
+    jobs: Dict[str, np.ndarray] | None = None,
 ) -> Statics:
-    """Expand per-type node constants into per-node arrays."""
+    """Expand per-type node constants into per-node arrays.
+
+    A 2-D bank with more rows than ``cfg.max_jobs`` streams its trace
+    through the job table: ``jobs``, the trace's columns (as passed to
+    ``load_jobs``, one per bank row), is then required and goes on the
+    device whole as ``Statics.trace``."""
     caps, types, idle, cdyn, gdyn, nmax, gflops = [], [], [], [], [], [], []
     for ti, t in enumerate(cfg.node_types):
         for _ in range(t.count):
@@ -171,6 +221,10 @@ def build_statics(
     rack_cap = np.zeros((cfg.n_racks,), np.float32)
     np.add.at(rack_cap, node_rack, np.array(nmax, np.float32))
     rack_r_th = cfg.rack_dt_full_load_c / np.maximum(rack_cap, 1.0)
+    rows = np.shape(trace_bank["cpu"])
+    trace = None
+    if len(rows) == 2 and rows[0] > J:
+        trace = _trace_columns(cfg, jobs, rows[0])
     return Statics(
         capacity=jnp.asarray(np.array(caps, np.float32).T),
         node_type=jnp.asarray(np.array(types, np.int32)),
@@ -186,6 +240,31 @@ def build_statics(
         gpu_trace=jnp.asarray(trace_bank["gpu"], jnp.float32),
         net_tx=jnp.asarray(trace_bank["net_tx"], jnp.float32),
         scenario=scenario if scenario is not None else default_scenario(cfg),
+        trace=trace,
+    )
+
+
+def _trace_columns(cfg: SimConfig, jobs, n: int) -> Trace:
+    from repro.utils.errors import ConfigError
+
+    if jobs is None or len(jobs["submit_t"]) != n:
+        got = "no jobs" if jobs is None else f"{len(jobs['submit_t'])} jobs"
+        raise ConfigError(
+            f"a bank of {n} rows streams its trace through the "
+            f"{cfg.max_jobs}-slot job table: pass its {n} jobs as jobs= "
+            f"(got {got})")
+    submit = np.asarray(jobs["submit_t"], np.float32)
+    due = np.append(np.minimum.accumulate(submit[::-1])[::-1], np.inf)
+    f32 = lambda k, d: jnp.asarray(jobs.get(k, np.full(n, d)), jnp.float32)
+    return Trace(
+        submit_t=jnp.asarray(submit),
+        dur=f32("dur", 0.0),
+        n_nodes=jnp.asarray(jobs["n_nodes"], jnp.int32),
+        req=jnp.asarray(jobs["req"], jnp.float32),
+        part=jnp.asarray(jobs.get("part", -np.ones(n)), jnp.int32),
+        priority=f32("priority", 0.0),
+        ckpt_interval=f32("ckpt_interval", cfg.ckpt_interval_s),
+        due=jnp.asarray(due, jnp.float32),
     )
 
 
@@ -283,7 +362,12 @@ def load_jobs(state: SimState, jobs: Dict[str, np.ndarray],
     priority, optionally ``part`` (int32 node-type index per job;
     -1 = any — the tag the ``partition`` placement enforces), and
     optionally ``ckpt_interval`` (per-job checkpoint period [s] overriding
-    ``cfg.ckpt_interval_s``; <=0 = no checkpoints); J' <= max_jobs.
+    ``cfg.ckpt_interval_s``; <=0 = no checkpoints).
+
+    A workload of more jobs than the table has slots streams: its first
+    ``max_jobs`` jobs fill the table, and ``state.stream`` starts the
+    cursor after them with every trace job's outcome QUEUED. The statics
+    must then hold the same trace (``build_statics(..., jobs=)``).
 
     The jobs dict is validated (``data.validate.validate_jobs``) before
     touching the table: a NaN duration or negative request would
@@ -301,7 +385,15 @@ def load_jobs(state: SimState, jobs: Dict[str, np.ndarray],
         jobs, _ = validate_jobs(jobs, mode=validate)
     J = state.jstate.shape[0]
     n = len(jobs["submit_t"])
-    assert n <= J, f"workload has {n} jobs > max_jobs {J}"
+    if n > J:
+        f = jnp.float32
+        state = state._replace(stream=Stream(
+            tid=jnp.arange(J, dtype=jnp.int32), cursor=jnp.int32(J),
+            jstate=jnp.full((n,), QUEUED, jnp.int32),
+            start_t=jnp.zeros((n,), f), end_t=jnp.zeros((n,), f)))
+        jobs = {k: v[..., :J] if k == "req" else v[:J]
+                for k, v in jobs.items()}
+        n = J
     sl = slice(0, n)
     if "ckpt_interval" in jobs:
         state = state._replace(ckpt_interval=state.ckpt_interval.at[sl].set(
@@ -319,3 +411,25 @@ def load_jobs(state: SimState, jobs: Dict[str, np.ndarray],
             jnp.asarray(jobs.get("priority", np.zeros(n)), jnp.float32)
         ),
     )
+
+
+def trace_records(state: SimState) -> Dict[str, np.ndarray]:
+    """Host copy of each job's outcome, in trace order: ``state`` (the
+    jstate code), ``start`` and ``end`` times. A streamed replica merges
+    the jobs still in the table over the record of those that left it;
+    jobs not admitted yet read QUEUED, 0, 0. On the resident path these
+    are the table's own columns."""
+    s = jax.device_get({"jstate": state.jstate, "start_t": state.start_t,
+                        "end_t": state.end_t, "stream": state.stream})
+    if s["stream"] is None:
+        rec = {k: np.asarray(s[k]) for k in ("jstate", "start_t", "end_t")}
+    else:
+        st = s["stream"]
+        tid = np.asarray(st.tid)
+        held = tid >= 0
+        rec = {}
+        for k in ("jstate", "start_t", "end_t"):
+            rec[k] = np.array(getattr(st, k))
+            rec[k][tid[held]] = np.asarray(s[k])[held]
+    return {"state": rec["jstate"], "start": rec["start_t"],
+            "end": rec["end_t"]}

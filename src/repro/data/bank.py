@@ -64,6 +64,14 @@ def stack_workloads(
     if not workloads:
         raise ValueError("stack_workloads needs at least one workload")
     J = cfg.max_jobs
+    longest = max(len(j["submit_t"]) for j, _ in workloads)
+    if longest > J:
+        from repro.utils.errors import ConfigError
+
+        raise ConfigError(
+            f"a workload of {longest} jobs exceeds the {J}-slot job table: "
+            "banked workloads stay resident (only run_episode/run_segment "
+            "stream a longer trace)")
     qmax = max(b["cpu"].shape[1] for _, b in workloads)
     def pad_net(a):
         out = np.zeros((J,), np.float32)

@@ -9,7 +9,9 @@ CPU / 100 ms GPU in the dataset; we band-average onto the sim quanta as
 RAPS does); per-job network traffic for the congestion model.
 
 ``synth_workload`` returns (jobs dict for ``load_jobs``, trace bank for
-``build_statics``).
+``build_statics``). A workload of more jobs than ``cfg.max_jobs`` streams
+through the job table (``core.state``): pass its jobs to
+``build_statics(..., jobs=)`` too.
 """
 
 from __future__ import annotations
@@ -32,7 +34,6 @@ def synth_workload(
     arrival: str = "poisson",      # 'poisson' | 'burst'
     net_heavy_fraction: float = 0.2,
 ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
-    assert n_jobs <= cfg.max_jobs
     rng = np.random.default_rng(seed)
     J = n_jobs
 
@@ -101,8 +102,8 @@ def synth_workload(
         # xeon-p8 split); consumed by load_jobs -> `partition` placement
         "part": np.where(is_gpu, gpu_ti, cpu_ti).astype(np.int32),
     }
-    # pad trace bank to max_jobs
-    Jmax = cfg.max_jobs
+    # pad trace bank to max_jobs (a longer trace keeps a row per job)
+    Jmax = max(cfg.max_jobs, J)
     bank = {
         "cpu": np.zeros((Jmax, Q), np.float32),
         "gpu": np.zeros((Jmax, Q), np.float32),

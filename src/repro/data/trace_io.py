@@ -9,6 +9,8 @@ The dataset ships as CSVs:
 
 ``load_supercloud`` parses these into the simulator workload + trace bank,
 band-averaging telemetry onto the sim's trace quanta exactly as RAPS does.
+Every job of the log is kept: a log longer than ``cfg.max_jobs`` streams
+through the job table (``core.state``).
 ``write_supercloud_csvs`` emits synthetic data in the same schema so the
 parser is exercised end-to-end offline (see DESIGN.md assumption table).
 """
@@ -115,8 +117,11 @@ def load_supercloud(
 ):
     """Parse SuperCloud-schema CSVs -> (jobs dict, trace bank).
 
-    Telemetry is averaged onto ``cfg.trace_quanta`` bands (RAPS trace
-    quanta); jobs without telemetry fall back to a constant 70% profile.
+    The bank has a row per job, padded to ``cfg.max_jobs``; with more jobs
+    than that the trace streams through the table (pass the jobs to
+    ``build_statics(..., jobs=)``). Telemetry is averaged onto
+    ``cfg.trace_quanta`` bands (RAPS trace quanta); jobs without telemetry
+    fall back to a constant 70% profile.
 
     ``validate`` (see :mod:`repro.data.validate`): ``"repair"`` (default)
     quarantines corrupt rows and keeps going; ``"strict"`` raises
@@ -134,13 +139,6 @@ def load_supercloud(
     rows, sched_rep = validate_sched_rows(
         rows, cfg, mode=validate, source=sched_file)
     J = len(rows)
-    if J > cfg.max_jobs:
-        sched_rep.warnings.append({
-            "row": cfg.max_jobs, "check": "truncated",
-            "detail": f"{J - cfg.max_jobs} valid job(s) beyond "
-                      f"cfg.max_jobs={cfg.max_jobs} dropped"})
-        rows = rows[: cfg.max_jobs]
-        J = cfg.max_jobs
 
     submit = np.array([float(r["time_submit"]) for r in rows], np.float32)
     start = np.array([float(r["time_start"]) for r in rows], np.float32)
@@ -155,7 +153,7 @@ def load_supercloud(
     job_ids = {int(r["job_id"]): i for i, r in enumerate(rows)}
 
     Q = max(int(np.ceil(dur.max() / cfg.trace_quanta)) + 1, 8)
-    Jmax = cfg.max_jobs
+    Jmax = max(cfg.max_jobs, J)
     cpu = np.zeros((Jmax, Q), np.float32)
     gpu = np.zeros((Jmax, Q), np.float32)
     cpu_n = np.zeros((Jmax, Q), np.float32)
@@ -181,8 +179,9 @@ def load_supercloud(
                 jid, t, u = parsed
                 rep.n_ok += 1
                 if jid not in job_ids:
-                    # jobs beyond max_jobs / quarantined jobs: skippable,
-                    # counted (not corrupt — the job just isn't loaded)
+                    # quarantined jobs / ids absent from the log:
+                    # skippable, counted (not corrupt — the job just
+                    # isn't loaded)
                     rep.n_skipped_unknown_id += 1
                     continue
                 j = job_ids[jid]

@@ -150,6 +150,9 @@ def test_fleet_with_thermals_shards_across_devices():
         assert hot.any(), "no replica crossed the trip threshold"
         for f in fs_ref._fields:
             a, b = getattr(fs_ref, f), getattr(fs_sh, f)
+            if a is None:   # the streamed-admission carry: resident here
+                assert b is None, f
+                continue
             if f == "key":
                 a, b = jax.random.key_data(a), jax.random.key_data(b)
             np.testing.assert_allclose(

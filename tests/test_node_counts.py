@@ -159,5 +159,8 @@ def test_count_path_drives_an_episode_like_the_scatter(monkeypatch):
         np.testing.assert_array_equal(np.asarray(getattr(fs2, f)),
                                       np.asarray(getattr(fs, f)), err_msg=f)
     for f, a in tel._asdict().items():
+        if a is None:   # streamed-admission counters: absent on this path
+            assert getattr(tel2, f) is None, f
+            continue
         np.testing.assert_allclose(np.asarray(getattr(tel2, f)),
                                    np.asarray(a), rtol=1e-6, err_msg=f)

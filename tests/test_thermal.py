@@ -191,6 +191,9 @@ def test_macro_bit_identical_with_thermals(scheduler):
     for f in tel._fields:
         if f == "macro_steps":
             continue
+        if getattr(tel, f) is None:   # streamed-admission counters: absent
+            assert getattr(tel2, f) is None, f   # on the resident path
+            continue
         np.testing.assert_allclose(
             np.asarray(getattr(tel, f)), np.asarray(getattr(tel2, f)),
             rtol=1e-6, atol=1e-9, err_msg=f"telemetry {f}")
