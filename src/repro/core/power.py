@@ -125,6 +125,37 @@ def node_counts(placement: jax.Array, n_nodes: int) -> jax.Array:
         default=lambda p: job_node_counts(p, n_nodes))
 
 
+def _gather_flagged_counts(placement: jax.Array, flags: jax.Array
+                           ) -> jax.Array:
+    """``flagged_counts`` as a gather of ``flags`` through the J*K slots
+    (the CPU's form)."""
+    valid = placement >= 0
+    safe = jnp.where(valid, placement, 0)
+    return jnp.sum(valid & jnp.take(flags, safe), axis=1).astype(jnp.float32)
+
+
+def _count_flagged_counts(placement: jax.Array, flags: jax.Array
+                          ) -> jax.Array:
+    """``flagged_counts`` as the node-count matrix contracted with the
+    flags (the accelerators' form: the TPU runs the gather element by
+    element, ~22 ms for 64 lanes at TX-GAIA size on one v5e, against
+    ~1 ms for a 64-lane count build)."""
+    cnt = job_node_counts(placement, flags.shape[-1])
+    return jnp.matmul(cnt, flags.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def flagged_counts(placement: jax.Array, flags: jax.Array) -> jax.Array:
+    """(J,) f32 count of each job's slots whose node is flagged in the
+    (N,) bool ``flags`` (slots < 0 count nowhere), in the form that is
+    cheap on the platform the program is lowered for (the gather on the
+    CPU, the node-count matrix elsewhere); exact small integers either
+    way."""
+    return jax.lax.platform_dependent(
+        placement, flags,
+        cpu=_gather_flagged_counts, default=_count_flagged_counts)
+
+
 def scatter_add_nodes(placement: jax.Array, amounts: jax.Array,
                       n_nodes: int, base: jax.Array | None = None
                       ) -> jax.Array:

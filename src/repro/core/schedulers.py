@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.sim import SimConfig
+from repro.core import power
 from repro.core.state import QUEUED, RUNNING, NRES, SimState, Statics
 
 BIG = 1e18
@@ -182,10 +183,7 @@ def shadow_time(cfg: SimConfig, state: SimState, statics: Statics,
         head_ok = head_ok & node_mask[head]
         free_ok = free_ok & node_mask[head]
     # nodes each running job will release THAT COULD HOST THE HEAD
-    valid = state.placement >= 0                              # (J, K)
-    safe = jnp.where(valid, state.placement, 0)
-    rel_nodes = jnp.sum(
-        valid & jnp.take(head_ok, safe), axis=1).astype(jnp.float32)
+    rel_nodes = power.flagged_counts(state.placement, head_ok)
     rel_nodes = jnp.where(running, rel_nodes, 0.0)
     order = jnp.argsort(est_end)
     cum = jnp.cumsum(rel_nodes[order])

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.sim import tx_gaia
 from repro.core import build_statics, init_state, make_macro_step, make_step
+from repro.core.placement import make_policy
 from repro.core.sim import _telem_zero
 from repro.kernels.node_power import node_power_pallas, power_scatter_pallas
 from repro.kernels.rack_thermal import rack_thermal_pallas
@@ -116,6 +117,34 @@ def slot_reductions(text, n_slots):
     return found
 
 
+def large_gathers(text, n_elems):
+    """Gathers of the optimised HLO ``text`` whose output has ``n_elems``
+    or more elements: a small table read through every job slot, which a
+    TPU runs element by element."""
+    return [f"gather {name}" for name, shape, opcode, _ in _instructions(text)
+            if opcode == "gather" and _elems(shape) >= n_elems]
+
+
+def _policy_step_text(sharding=None):
+    """Optimised HLO of the TX-GAIA policy-mode step (a traced
+    ``Policy``, EASY's backfill among the switch's branches), compiled for
+    ``sharding``'s device or, without one, for the CPU."""
+    cfg = tx_gaia()
+    statics = build_statics(cfg)
+    state = init_state(cfg, statics, jax.random.key(0))
+    args = (statics, state, make_policy("easy", "first_fit"))
+
+    def step(st, s, p):
+        return make_step(cfg, st, p)(s, jnp.int32(-1))
+
+    if sharding is None:
+        return jax.jit(step).lower(*args).compile().as_text()
+    return _compile(step, *(_sds(a, sharding) for a in args)).as_text()
+
+
+DISPATCH_COUNTS = re.compile(r'tick\.dispatch/[^"]*tick\.node_counts')
+
+
 def test_power_scatter_compiles_at_tx_gaia_width(one_chip):
     jk, n = 512 * 64, 672           # tx_gaia(): max_jobs x max_nodes_per_job
     f = lambda *a: power_scatter_pallas(*a, **RECT, interpret=False)
@@ -179,6 +208,24 @@ def test_tx_gaia_step_keeps_the_scatter_on_cpu():
     text = jax.jit(lambda st, s: make_step(cfg, st, "fcfs")(
         s, jnp.int32(-1))).lower(statics, state).compile().as_text()
     assert len(slot_reductions(text, SLOTS)) >= 2
+    assert "tick.node_counts" not in text
+
+
+def test_tx_gaia_policy_step_counts_releases_through_the_matrix(one_chip):
+    """On the chip, EASY's count of head-capable releases contracts the
+    node-count matrix: no gather through the J*K slots, and the build is
+    named under the dispatch."""
+    text = _policy_step_text(one_chip)
+    assert large_gathers(text, SLOTS) == []
+    assert slot_reductions(text, SLOTS) == []
+    assert DISPATCH_COUNTS.search(text)
+
+
+def test_tx_gaia_policy_step_keeps_the_gather_on_cpu():
+    """The same step lowered for the CPU keeps the slot gather (cheap
+    there), which ``large_gathers`` finds."""
+    text = _policy_step_text()
+    assert len(large_gathers(text, SLOTS)) >= 1
     assert "tick.node_counts" not in text
 
 
