@@ -246,7 +246,7 @@ def run_episode_snapshotted(
             ckpt.save(snapshot_dir, ticks, {"state": state, "acc": acc},
                       extra_meta={"ticks": ticks, "fingerprint": fp},
                       keep=snapshot_keep)
-    return state, sim._telem_finalize(acc)
+    return state, sim.telem_finalize(acc)
 
 
 def run_fleet_snapshotted(
@@ -292,9 +292,8 @@ def run_fleet_snapshotted(
             "policies": "none" if policies is None
             else _tree_digest(policies),
         })
-    z = sim._telem_zero(cfg.resilience_on, cfg.serving_on)
-    acc = jax.tree.map(
-        lambda a: jnp.broadcast_to(a, (R,) + jnp.shape(a)), z)
+    acc = jax.tree.map(lambda a: jnp.broadcast_to(a, (R,) + jnp.shape(a)),
+                       sim.telem_zero(cfg, statics))
     ticks = 0
     if resume_from is not None:
         tree, ticks = _restore_latest(
@@ -302,23 +301,17 @@ def run_fleet_snapshotted(
         if tree is not None:
             state, acc = tree["state"], tree["acc"]
 
-    kw_items = tuple(sorted(kw.items()))
     while ticks < n_steps:
         n = int(min(seg_ticks, n_steps - ticks))
-        if mesh is not None:
-            state, acc = fleet._fleet_segment_sharded(
-                cfg, statics, scenarios, policies, state, acc, n,
-                scheduler, kw_items, mesh, mesh_axis)
-        else:
-            state, acc = fleet._fleet_segment(
-                cfg, statics, scenarios, policies, state, acc, n,
-                scheduler, kw_items)
+        state, acc = fleet.fleet_segment(
+            cfg, statics, scenarios, policies, state, acc, n, scheduler, kw,
+            mesh=mesh, mesh_axis=mesh_axis)
         ticks += n
         if snapshot_dir is not None:
             ckpt.save(snapshot_dir, ticks, {"state": state, "acc": acc},
                       extra_meta={"ticks": ticks, "fingerprint": fp},
                       keep=snapshot_keep)
-    return state, jax.vmap(sim._telem_finalize)(acc)
+    return state, jax.vmap(sim.telem_finalize)(acc)
 
 
 __all__ = [

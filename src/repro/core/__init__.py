@@ -56,6 +56,7 @@ from repro.core.sim import (
     run_segment,
     summary,
     summary_columns,
+    telem_finalize,
     telem_zero,
 )
 from repro.core.state import (

@@ -8,15 +8,23 @@ replicas, installs a per-replica ``Scenario`` (batched pytree from
 ``scenarios.stack_scenarios`` / ``sample_scenarios``) and optionally a
 per-replica ``placement.Policy`` (batched (select_id, place_id) int32s
 from ``placement.stack_policies`` / ``policy_grid``), splits the PRNG key
-per replica, and runs ``vmap(lax.scan(step))`` under a single ``jit`` —
-the policy x scenario sweep engine for the paper's sustainability-policy
+per replica, and runs ``vmap`` of ``run_episode`` under a single ``jit``
+— the policy x scenario sweep engine for the paper's sustainability-policy
 studies. Because policies are data (ids resolved by ``lax.switch`` inside
 the step), the whole grid costs ONE compilation, not one per policy.
 
-Memory notes: the replica-batched state and key buffers are DONATED to the
-compiled call (XLA reuses them for the final states), and the telemetry
-knobs (``telemetry_every`` / ``summary_only``, forwarded to
-``run_episode``) replace the O(R * n_steps * 16) stacked ``StepOut`` with
+One program serves every fleet call: ``_fleet``, one per-replica body
+under ``vmap``, inside a ``shard_map`` only when a mesh is given. A sweep
+installs each replica's key and runs ``run_episode``; a segment
+(``fleet_segment``, the unit of a snapshotted sweep in
+``checkpoint.episode``) threads raw telemetry accumulators through
+``run_segment``. Which of the two is static, so each compiles its own
+program from the same code.
+
+Memory notes: the replica-batched state and key (or accumulator) buffers
+are DONATED to the compiled call (XLA reuses them for the final states),
+and the telemetry knobs (``telemetry_every`` / ``summary_only``, forwarded
+to ``run_episode``) replace the O(R * n_steps * 16) stacked ``StepOut`` with
 windowed or O(R * 16) episode-wide reductions — fleet-sweep memory then no
 longer scales with ``n_steps``.
 
@@ -143,26 +151,6 @@ def policy_scenario_grid(
     return crossed, stack_scenarios(scns * len(pols))
 
 
-# Module-level so repeated run_fleet calls with the same static config reuse
-# the compiled executable (cfg is a frozen dataclass => hashable; statics /
-# scenarios / policies / state / keys are traced). ``state``/``keys``
-# arrive replica-batched and are donated: XLA reuses their buffers for the
-# final states.
-@partial(jax.jit, static_argnames=("cfg", "n_steps", "scheduler", "kw_items"),
-         donate_argnames=("state", "keys"))
-def _fleet(cfg, statics, scenarios, policies, state, keys, n_steps,
-           scheduler, kw_items):
-    kw = dict(kw_items)
-
-    def one(scn: Scenario, pol, key: jax.Array, st: SimState):
-        st = st._replace(key=key)
-        stt = statics._replace(scenario=scn)
-        who = scheduler if pol is None else pol
-        return run_episode(cfg, stt, st, n_steps, who, **kw)
-
-    return jax.vmap(one)(scenarios, policies, keys, state)
-
-
 # Varying-manual-axes checking is off for the fleet's shard_map: the
 # per-replica program runs no collective, so the check has nothing to
 # guard, and with it on every fresh constant carry of the episode drivers'
@@ -171,91 +159,76 @@ def _fleet(cfg, statics, scenarios, policies, state, keys, n_steps,
 _CHECK_VMA = False
 
 
-# Sharded twin of ``_fleet``: the same per-replica ``one`` under the same
-# inner ``vmap``, but partitioned across ``mesh``'s fleet axis by shard_map
-# so each device's R/D-lane while-loops run their own trip counts (no
-# collectives inside => no cross-shard lockstep). ``mesh`` is hashable and
-# rides the jit static cache alongside cfg; state/keys donation is
-# per-device buffer reuse here.
+# The one fleet program. Module-level so repeated calls with the same
+# static config reuse the compiled executable (cfg is a frozen dataclass
+# => hashable, and so is ``mesh``; statics / scenarios / policies / state
+# / carry are traced). ``state`` and ``carry`` arrive replica-batched and
+# are donated: XLA reuses their buffers (per device under a mesh) for the
+# results, so they must be distinct buffers.
 @partial(jax.jit,
          static_argnames=("cfg", "n_steps", "scheduler", "kw_items", "mesh",
-                          "axis"),
-         donate_argnames=("state", "keys"))
-def _fleet_sharded(cfg, statics, scenarios, policies, state, keys, n_steps,
-                   scheduler, kw_items, mesh, axis):
+                          "axis", "segment"),
+         donate_argnames=("state", "carry"))
+def _fleet(cfg, statics, scenarios, policies, state, carry, n_steps,
+           scheduler, kw_items, mesh=None, axis=FLEET_AXIS, segment=False):
+    """``n_steps`` of every replica: ``vmap`` of one per-replica body, and
+    with a ``mesh`` that ``vmap`` inside a ``shard_map`` over ``axis``, so
+    each device's R/D-lane while-loops run their own trip counts.
+
+    A sweep (``segment=False``) installs each replica's PRNG key from
+    ``carry`` and runs ``run_episode``. A segment (``segment=True``,
+    ``fleet_segment``) threads the raw accumulators ``carry`` through
+    ``run_segment``; its keys are already in ``state``."""
     kw = dict(kw_items)
 
-    def shard(statics, scenarios, policies, keys, state):
-        def one(scn: Scenario, pol, key: jax.Array, st: SimState):
-            st = st._replace(key=key)
+    def fleet(statics, scenarios, policies, state, carry):
+        def one(scn: Scenario, pol, st: SimState, c):
             stt = statics._replace(scenario=scn)
             who = scheduler if pol is None else pol
-            return run_episode(cfg, stt, st, n_steps, who, **kw)
+            if segment:
+                return run_segment(cfg, stt, st, c, n_steps, who, **kw)
+            return run_episode(cfg, stt, st._replace(key=c), n_steps, who,
+                               **kw)
 
-        return jax.vmap(one)(scenarios, policies, keys, state)
+        return jax.vmap(one)(scenarios, policies, state, carry)
 
-    # per-leaf spec pytrees from sharding.specs: statics replicate, every
-    # replica-batched operand splits its leading axis; the output prefix
-    # spec P(axis) matches (SimState, StepOut|TelemetrySummary) alike
+    args = (statics, scenarios, policies, state, carry)
+    if mesh is None:
+        return fleet(*args)
+    # statics replicate, every replica-batched operand splits its leading
+    # axis; the output prefix spec P(axis) matches (SimState, StepOut|
+    # TelemetrySummary) alike
     return jax.shard_map(
-        shard, mesh=mesh,
+        fleet, mesh=mesh,
         in_specs=(replicated_pspecs(statics),
-                  fleet_pspecs(scenarios, axis), fleet_pspecs(policies, axis),
-                  fleet_pspecs(keys, axis), fleet_pspecs(state, axis)),
+                  *(fleet_pspecs(a, axis) for a in args[1:])),
         out_specs=PartitionSpec(axis), check_vma=_CHECK_VMA,
-    )(statics, scenarios, policies, keys, state)
+    )(*args)
 
 
-# Segment twins of ``_fleet``/``_fleet_sharded`` for snapshot/resume
-# (checkpoint.episode): the same per-replica program cut at a tick
-# boundary, threading a RAW TelemetrySummary accumulator instead of
-# zero-init + finalize — keys are pre-installed in ``state`` (split/
-# fold_in happens ONCE per run, not per segment, so resumed PRNG streams
-# continue exactly where the uninterrupted run would be).
-@partial(jax.jit, static_argnames=("cfg", "n_ticks", "scheduler", "kw_items"),
-         donate_argnames=("state", "acc"))
-def _fleet_segment(cfg, statics, scenarios, policies, state, acc, n_ticks,
-                   scheduler, kw_items):
-    kw = dict(kw_items)
-    macro = bool(kw.pop("macro", False))
-    kw.pop("summary_only", None)
-    kw.pop("telemetry_every", None)
-
-    def one(scn: Scenario, pol, st: SimState, a):
-        stt = statics._replace(scenario=scn)
-        who = scheduler if pol is None else pol
-        return run_segment(cfg, stt, st, a, n_ticks, who, macro=macro, **kw)
-
-    return jax.vmap(one)(scenarios, policies, state, acc)
+# chipbench/tests/test_tpu_compile.py lowers the fleet program by this name
+_fleet_sharded = _fleet
 
 
-@partial(jax.jit,
-         static_argnames=("cfg", "n_ticks", "scheduler", "kw_items", "mesh",
-                          "axis"),
-         donate_argnames=("state", "acc"))
-def _fleet_segment_sharded(cfg, statics, scenarios, policies, state, acc,
-                           n_ticks, scheduler, kw_items, mesh, axis):
-    kw = dict(kw_items)
-    macro = bool(kw.pop("macro", False))
-    kw.pop("summary_only", None)
-    kw.pop("telemetry_every", None)
-
-    def shard(statics, scenarios, policies, state, acc):
-        def one(scn: Scenario, pol, st: SimState, a):
-            stt = statics._replace(scenario=scn)
-            who = scheduler if pol is None else pol
-            return run_segment(cfg, stt, st, a, n_ticks, who,
-                               macro=macro, **kw)
-
-        return jax.vmap(one)(scenarios, policies, state, acc)
-
-    return jax.shard_map(
-        shard, mesh=mesh,
-        in_specs=(replicated_pspecs(statics),
-                  fleet_pspecs(scenarios, axis), fleet_pspecs(policies, axis),
-                  fleet_pspecs(state, axis), fleet_pspecs(acc, axis)),
-        out_specs=PartitionSpec(axis), check_vma=_CHECK_VMA,
-    )(statics, scenarios, policies, state, acc)
+def fleet_segment(cfg: SimConfig, statics: Statics, scenarios: Scenario,
+                  policies: Policy | None, state: SimState,
+                  acc: TelemetrySummary, n_ticks: int, scheduler: str,
+                  kw: dict, *, mesh=None, mesh_axis: str = FLEET_AXIS
+                  ) -> Tuple[SimState, TelemetrySummary]:
+    """Advance a replica-batched fleet by ``n_ticks``: ``run_segment`` for
+    every replica in the fleet's one compiled program, threading raw
+    per-replica ``TelemetrySummary`` accumulators ``acc``. ``state``
+    carries its keys (split once per run, so resumed streams continue
+    exactly); ``scenarios``/``policies``/``scheduler`` are ``run_fleet``'s
+    normalised ones and ``kw`` its forwarded keywords, less the
+    episode-wide telemetry modes a segment does not have. The unit of a
+    snapshotted sweep (``checkpoint.episode``); ``state`` and ``acc`` are
+    donated."""
+    kw_items = tuple(sorted(
+        (k, v) for k, v in kw.items()
+        if k not in ("summary_only", "telemetry_every")))
+    return _fleet(cfg, statics, scenarios, policies, state, acc, n_ticks,
+                  scheduler, kw_items, mesh, mesh_axis, segment=True)
 
 
 def shard_fleet(tree, mesh, axis: str = FLEET_AXIS):
@@ -384,16 +357,16 @@ def run_fleet(
     ``run_fleet`` call for chained sweeps. A batched state's buffers are
     donated to the compiled call and must not be reused afterwards.
 
-    ``mesh``: a 1-D fleet mesh (``launch.mesh.make_fleet_mesh``) switches
-    execution to the device-sharded path — the replica axis splits in
-    contiguous blocks across ``mesh_axis`` via shard_map with the same
-    per-shard ``vmap`` inside, so macro while-loops lockstep only within
-    a shard (see module docstring) and memory/donation happen per device.
-    R must divide evenly by the mesh size (loud error otherwise — a
-    silent pad would fabricate replicas whose summaries leak into sweep
-    statistics). Results are bit-identical to ``mesh=None`` run on each
-    device's block of replicas, and equal to one ``mesh=None`` call over
-    all of them up to float rounding (see the module docstring).
+    ``mesh``: a 1-D fleet mesh (``launch.mesh.make_fleet_mesh``) wraps the
+    fleet program's ``vmap`` in a shard_map — the replica axis splits in
+    contiguous blocks across ``mesh_axis``, so macro while-loops lockstep
+    only within a shard (see module docstring) and memory/donation happen
+    per device. R must divide evenly by the mesh size (loud error
+    otherwise, snapshotted sweeps included — a silent pad would fabricate
+    replicas whose summaries leak into sweep statistics). Results are
+    bit-identical to ``mesh=None`` run on each device's block of replicas,
+    and equal to one ``mesh=None`` call over all of them up to float
+    rounding (see the module docstring).
 
     ``**kw`` forwards to ``run_episode``/``make_step`` — in particular
     ``summary_only=True`` returns per-replica ``TelemetrySummary`` with
@@ -409,6 +382,7 @@ def run_fleet(
     one crash-atomic snapshot of the whole replica-batched state (keys
     installed, so resumed streams continue exactly) plus raw telemetry
     accumulators; resume is bit-identical to the uninterrupted sweep.
+    Each segment runs through ``fleet_segment``, the same fleet program.
     Requires ``summary_only=True`` or ``macro=True``.
 
     Returns (final_states, outs) with a leading replica axis on every leaf.
@@ -417,17 +391,7 @@ def run_fleet(
         scheduler, scenarios, policies, state, keys = _fleet_inputs(
             statics, state, scheduler, scenarios, policies, workloads)
         R = n_replicas(scenarios)
-    kw_items = tuple(sorted(kw.items()))
-    if snapshot_every_s is not None or resume_from is not None \
-            or snapshot_dir is not None:
-        from repro.checkpoint.episode import run_fleet_snapshotted
-
-        out = run_fleet_snapshotted(
-            cfg, statics, scenarios, policies, state, keys, n_steps,
-            scheduler, kw, mesh=mesh, mesh_axis=mesh_axis,
-            snapshot_every_s=snapshot_every_s, snapshot_dir=snapshot_dir,
-            resume_from=resume_from, snapshot_keep=snapshot_keep)
-    elif mesh is not None:
+    if mesh is not None:
         if mesh_axis not in mesh.shape:
             raise ConfigError(
                 f"mesh has axes {tuple(mesh.shape)}, no {mesh_axis!r} — "
@@ -439,14 +403,20 @@ def run_fleet(
                 f"{mesh_axis!r}-axis devices — a silent pad would "
                 "fabricate replicas; pick R as a multiple of the mesh "
                 "size or shrink the mesh (make_fleet_mesh(n_devices=...))")
-        with jax.profiler.TraceAnnotation("host.fleet.call"):
-            out = _fleet_sharded(cfg, statics, scenarios, policies, state,
-                                 keys, n_steps, scheduler, kw_items, mesh,
-                                 mesh_axis)
+    if snapshot_every_s is not None or resume_from is not None \
+            or snapshot_dir is not None:
+        from repro.checkpoint.episode import run_fleet_snapshotted
+
+        out = run_fleet_snapshotted(
+            cfg, statics, scenarios, policies, state, keys, n_steps,
+            scheduler, kw, mesh=mesh, mesh_axis=mesh_axis,
+            snapshot_every_s=snapshot_every_s, snapshot_dir=snapshot_dir,
+            resume_from=resume_from, snapshot_keep=snapshot_keep)
     else:
         with jax.profiler.TraceAnnotation("host.fleet.call"):
             out = _fleet(cfg, statics, scenarios, policies, state, keys,
-                         n_steps, scheduler, kw_items)
+                         n_steps, scheduler, tuple(sorted(kw.items())),
+                         mesh, mesh_axis)
     if invariants.enabled():
         # post-hoc eager audit of every replica's final state (the checks
         # broadcast over the leading replica axis); the per-step checkify

@@ -179,7 +179,12 @@ def apply_serving(
     backoff = retry_backoff(cfg, jnp.arange(inc.shape[0]))
     got = inc > 0.0
     retry_t = jnp.where(got, jnp.minimum(retry_t, t + backoff), retry_t)
-    retry_q = retry_q + inc
+    # the select (a no-op: inc >= 0) keeps ``queue * bfrac`` from feeding
+    # this add directly. XLA:CPU contracts a multiply and an add that share
+    # a fusion into one FMA, and whether they share one depends on the
+    # program around the step: the per-tick and macro programs rounded
+    # this sum differently on the same event tick
+    retry_q = retry_q + jnp.where(got, inc, 0.0)
 
     state = state._replace(
         srv_queue=queue, srv_retry_q=retry_q, srv_retry_t=retry_t,

@@ -770,36 +770,29 @@ _SRV_TELEM = ("srv_arrived", "srv_completed", "srv_shed", "srv_dropped",
 _ADMIT_TELEM = ("admitted", "admit_overflow", "live_slot_ticks")
 
 
-def _telem_zero(resilience_on: bool = True,
-                serving_on: bool = False,
-                streamed: bool = False) -> TelemetrySummary:
+def telem_zero(cfg: SimConfig, statics: Statics) -> TelemetrySummary:
+    """The empty raw accumulator of one replica of ``cfg`` over
+    ``statics`` (``run_segment``'s ``acc`` at an episode's start)."""
     z = jnp.float32(0.0)
     acc = TelemetrySummary(*([z] * len(TelemetrySummary._fields)))
-    if not resilience_on:
+    if not cfg.resilience_on:
         # With the fault engine off the killed/lost accumulators would be
         # constant zeros — but even two dead loop-carried leaves perturb
         # XLA's scan-body codegen enough to shift float rounding elsewhere
         # in the step (observed: 1e-6 work_left drift on the thermal
         # macro-vs-per-tick bit-identity pin). ``None`` is an EMPTY pytree
         # node, so the compiled carry is leaf-for-leaf the legacy program;
-        # ``_telem_finalize`` restores concrete zeros for consumers.
+        # ``telem_finalize`` restores concrete zeros for consumers.
         acc = acc._replace(killed=None, lost_node_s=None)
-    if serving_on:
+    if cfg.serving_on:
         acc = acc._replace(srv_lat_hist=jnp.zeros((8,), jnp.float32))
     else:
         # same XLA-codegen hazard as killed/lost above: the serving
         # accumulators ride as empty nodes when the plane is off
         acc = acc._replace(**{f: None for f in _SRV_TELEM})
-    if not streamed:
+    if statics.trace is None:
         acc = acc._replace(**{f: None for f in _ADMIT_TELEM})
     return acc
-
-
-def telem_zero(cfg: SimConfig, statics: Statics) -> TelemetrySummary:
-    """The empty raw accumulator of one replica of ``cfg`` over
-    ``statics`` (``run_segment``'s ``acc`` at an episode's start)."""
-    return _telem_zero(cfg.resilience_on, cfg.serving_on,
-                       streamed=statics.trace is not None)
 
 
 @_scoped("tick.telemetry")
@@ -807,7 +800,7 @@ def _telem_update(acc: TelemetrySummary, out: StepOut,
                   macro_inc: jax.Array | float = 1.0,
                   resilience_on: bool = True,
                   serving_on: bool = False) -> TelemetrySummary:
-    # mean_* fields hold running sums until _telem_finalize divides by n.
+    # mean_* fields hold running sums until telem_finalize divides by n.
     # The killed/lost (and serving) adds are Python-gated: with the engine
     # off the addends are constant zeros, but even dead adds perturb XLA's
     # scan-body codegen enough to shift float rounding elsewhere in the
@@ -868,7 +861,10 @@ def _telem_update(acc: TelemetrySummary, out: StepOut,
     )
 
 
-def _telem_finalize(acc: TelemetrySummary) -> TelemetrySummary:
+def telem_finalize(acc: TelemetrySummary) -> TelemetrySummary:
+    """The episode summary of a raw accumulator: running sums of the
+    ``mean_*`` fields divided by the ticks, and concrete zeros for the
+    fields a disabled subsystem carried as empty nodes."""
     n = jnp.maximum(acc.n_steps, 1.0)
     acc = acc._replace(**{
         f: getattr(acc, f) / n
@@ -901,6 +897,11 @@ def _telem_finalize(acc: TelemetrySummary) -> TelemetrySummary:
 # telemetry-count machinery entirely.
 
 _BIG_T = jnp.float32(jnp.inf)
+
+# Longest quiet horizon a macro step looks ahead (ticks), and how many fast
+# ticks of the count-matrix power path one chunked gemm covers.
+HORIZON_CAP = 4096
+CHUNK_TICKS = 16
 
 # SimState leaves a fast tick may change; everything else provably keeps
 # its segment-start value, so the commit-select only touches these.
@@ -999,7 +1000,7 @@ def quiet_horizon(
     state: SimState,
     scheduler: str | Policy = "fcfs",
     *,
-    max_ticks: int = 4096,
+    max_ticks: int = HORIZON_CAP,
     assume_undispatchable: bool | jax.Array = False,
 ) -> jax.Array:
     """Number of ticks after ``state.t`` guaranteed quiet (int32 >= 0).
@@ -1069,8 +1070,6 @@ def make_macro_step(
     reward_weights: Tuple[float, ...] = (1.0, 1.0, 1.0, 0.05),
     use_power_kernel: bool = False,
     use_thermal_kernel: bool = False,
-    horizon_cap: int = 4096,
-    chunk_ticks: int = 16,
     update=None,
 ):
     """Returns ``macro_step(state, acc, max_ticks) -> (state, acc, ticks)``:
@@ -1087,7 +1086,7 @@ def make_macro_step(
     power path is shared (the dense-scatter budget, i.e. every test-sized
     config). On
     larger configs the fast tick refreshes per-node loads through a
-    per-segment job->node count matrix — one ``chunk_ticks``-wide gemm
+    per-segment job->node count matrix — one ``CHUNK_TICKS``-wide gemm
     instead of a J*K scatter per tick; the different summation order
     leaves energy/cost/carbon within float-accumulation tolerance of the
     per-tick path (job/queue
@@ -1123,7 +1122,7 @@ def make_macro_step(
     trip_c = jnp.float32(cfg.thermal_trip_c)
     fast_fields = _fast_fields(cfg)
     N = cfg.n_nodes
-    C = max(int(chunk_ticks), 1)
+    C = CHUNK_TICKS
     # shared power path (bit-identical to the full step) whenever the
     # per-tick scatter is already the dense contraction; the chunked
     # count-matrix gemm otherwise (see docstring)
@@ -1182,15 +1181,15 @@ def make_macro_step(
 
         # --- segment constants (all provably frozen across quiet ticks).
         with jax.named_scope("macro.horizon"):
-            # NB net_load carries a cross-job reduction: XLA may fuse it
-            # differently here than in the per-tick program, so telemetry
-            # means can skew an ulp vs per-tick runs (the documented
-            # float-accumulation tolerance); job/queue state never
-            # consumes it
+            # NB the per-tick program may contract net_load's division (a
+            # reciprocal multiply) and the telemetry add into one FMA where
+            # this one does not, so telemetry means can skew by ulps vs
+            # per-tick runs (the documented float-accumulation tolerance);
+            # job/queue state never consumes it
             rate, net_load = congestion_slowdown(cfg, state, statics)
             next_event_t, visible_now, k_time, _ = _horizon_parts(
                 cfg, state, statics, rate, dispatch_on, replay_gated,
-                eligibility_vis, horizon_cap)
+                eligibility_vis, HORIZON_CAP)
             # dispatch gate: if the full tick started something AND jobs are
             # still visible, the leftovers may now be servable — keep per-tick
             # stepping. A start that DRAINED the queue, or a no-start with a
@@ -1206,7 +1205,7 @@ def make_macro_step(
                 # detection's suspenders: keeps segments from even entering
                 # the neighborhood of a trip crossing un-checked)
                 k_quiet = jnp.minimum(k_quiet, thm.thermal_crossing_horizon(
-                    cfg, statics, state, horizon_cap))
+                    cfg, statics, state, HORIZON_CAP))
             blocked = started & visible_now
             if streamed:
                 blocked = blocked | _overflowing(statics, state)
@@ -1215,7 +1214,7 @@ def make_macro_step(
                 # stay per-tick while the queue sits over a threshold (the
                 # next tick's sweep WILL move mass): overload IS the event
                 k_quiet = jnp.minimum(k_quiet, srv.serving_crossing_horizon(
-                    cfg, state, statics, horizon_cap))
+                    cfg, state, statics, HORIZON_CAP))
                 blocked = blocked | srv.serving_trigger(cfg, state)
             budget = jnp.where(blocked, 0, k_quiet)
             queued, running, util = _counts_and_util(state, statics)
@@ -1315,6 +1314,83 @@ def _where_leaf(pred, old, new):
     return jnp.where(pred, old, new)
 
 
+def _macro_advance(mstep, state: SimState, acc, n_ticks: int):
+    """Drive ``mstep(state, acc, max_ticks) -> (state, acc, ticks)`` until
+    exactly ``n_ticks`` ticks are committed: each macro step is clamped to
+    the ticks left, so the boundary is a forced breakpoint."""
+    def body(c):
+        s, a, ticks = c
+        s, a, took = mstep(s, a, n_ticks - ticks)
+        return (s, a, ticks + took)
+
+    s, a, _ = jax.lax.while_loop(
+        lambda c: c[2] < n_ticks, body, (state, acc, jnp.int32(0)))
+    return s, a
+
+
+def _eager_checks(state: SimState) -> bool:
+    """Whether the ``REPRO_CHECKIFY`` per-step harness instruments this
+    call: on, and ``state`` concrete (a traced caller re-checks itself)."""
+    from repro.utils import invariants
+
+    return invariants.enabled() and not isinstance(state.t, jax.core.Tracer)
+
+
+def _advancer(cfg: SimConfig, statics: Statics, scheduler, macro: bool,
+              check: bool, kw: dict):
+    """``advance(state, acc, n_ticks)``, the one loop behind every episode
+    driver: the macro while-loop over ``(state, acc, ticks)``, or a per-tick
+    scan folding each ``StepOut`` into the raw accumulator ``acc``. With
+    ``acc=None`` (per-tick only) the scan stacks the per-tick ``StepOut``s
+    instead. ``check`` runs the machine-invariant suite after every
+    committed step."""
+    from repro.utils import invariants
+
+    if macro:
+        fn = make_macro_step(cfg, statics, scheduler, **kw)
+    else:
+        fn = make_step(cfg, statics, scheduler, **kw)
+    if check:
+        raw = fn
+
+        def fn(*args):
+            out = raw(*args)
+            invariants.check_state(cfg, statics, out[0])
+            return out
+
+    if macro:
+        return functools.partial(_macro_advance, fn)
+
+    def advance(state, acc, n_ticks):
+        if acc is None:
+            return jax.lax.scan(lambda s, _: fn(s, jnp.int32(-1)), state,
+                                None, length=n_ticks)
+
+        def accum_body(carry, _):
+            s, a = carry
+            s, out = fn(s, jnp.int32(-1))
+            return (s, _telem_update(
+                a, out, resilience_on=cfg.resilience_on,
+                serving_on=cfg.serving_on)), None
+
+        return jax.lax.scan(accum_body, (state, acc), None,
+                            length=n_ticks)[0]
+
+    return advance
+
+
+def _checkified(check: bool, go, *args):
+    """``go(*args)``, under ``checkify`` when ``check`` (raising on the
+    first violating tick)."""
+    if not check:
+        return go(*args)
+    from jax.experimental import checkify
+
+    err, out = checkify.checkify(go)(*args)
+    err.throw()
+    return out
+
+
 def run_episode(
     cfg: SimConfig,
     statics: Statics,
@@ -1343,6 +1419,8 @@ def run_episode(
         (stacked, length ``n_steps // k``) — O(n_steps/k) memory;
       - ``summary_only=True``: a single episode-wide ``TelemetrySummary``
         accumulated in the scan carry — O(1) memory in ``n_steps``.
+    A summary is ``telem_zero`` -> ``run_segment`` over the window ->
+    ``telem_finalize``; windows are a ``lax.scan`` of that.
 
     ``macro=True`` drives the episode with ``make_macro_step``: quiet
     ticks (no arrival/completion/dispatch/failure/cap breakpoint) are
@@ -1371,7 +1449,6 @@ def run_episode(
     and an eager (un-jitted) call; with snapshotting off this path adds
     literally nothing to the traced step.
     """
-    from repro.utils import invariants
     from repro.utils.errors import ConfigError
 
     if summary_only and telemetry_every > 1:
@@ -1394,91 +1471,26 @@ def run_episode(
             macro=macro, snapshot_every_s=snapshot_every_s,
             snapshot_dir=snapshot_dir, resume_from=resume_from,
             snapshot_keep=snapshot_keep, kw=kw)
-    check_on = invariants.enabled() and not isinstance(
-        state.t, jax.core.Tracer)
+    check = _eager_checks(state)
+    advance = _advancer(cfg, statics, scheduler, macro, check, kw)
 
-    if macro:
-        mstep = make_macro_step(cfg, statics, scheduler, **kw)
-        if check_on:
-            raw_mstep = mstep
+    def window(state, n):
+        state, acc = advance(state, telem_zero(cfg, statics), n)
+        return state, telem_finalize(acc)
 
-            def mstep(s, a, n):
-                s, a, took = raw_mstep(s, a, n)
-                invariants.check_state(cfg, statics, s)
-                return s, a, took
-
-        def run_window(state, n):
-            def wcond(c):
-                return c[2] < n
-
-            def wbody(c):
-                s, a, ticks = c
-                s, a, took = mstep(s, a, n - ticks)
-                return (s, a, ticks + took)
-
-            s, a, _ = jax.lax.while_loop(
-                wcond, wbody,
-                (state, telem_zero(cfg, statics),
-                 jnp.int32(0)))
-            return s, _telem_finalize(a)
-
-        if telemetry_every <= 1:
-            def go(state):
-                return run_window(state, n_steps)
-        else:
-            def go(state):
-                return jax.lax.scan(
-                    lambda s, _: run_window(s, telemetry_every), state,
-                    None, length=n_steps // telemetry_every)
+    if telemetry_every > 1:
+        def go(state):
+            return jax.lax.scan(
+                lambda s, _: window(s, telemetry_every), state, None,
+                length=n_steps // telemetry_every)
+    elif macro or summary_only:
+        def go(state):
+            return window(state, n_steps)
     else:
-        step = make_step(cfg, statics, scheduler, **kw)
-        if check_on:
-            raw_step = step
+        def go(state):
+            return advance(state, None, n_steps)
 
-            def step(s, a):
-                s, out = raw_step(s, a)
-                invariants.check_state(cfg, statics, s)
-                return s, out
-
-        def body(s, _):
-            return step(s, jnp.int32(-1))
-
-        def accum_body(carry, _):
-            s, acc = carry
-            s, out = step(s, jnp.int32(-1))
-            return (s, _telem_update(
-                acc, out, resilience_on=cfg.resilience_on,
-                serving_on=cfg.serving_on)), None
-
-        if summary_only:
-            def go(state):
-                (fs, acc), _ = jax.lax.scan(
-                    accum_body,
-                    (state, telem_zero(cfg, statics)),
-                    None, length=n_steps)
-                return fs, _telem_finalize(acc)
-        elif telemetry_every <= 1:
-            def go(state):
-                return jax.lax.scan(body, state, None, length=n_steps)
-        else:
-            def window(s, _):
-                (s, acc), _ = jax.lax.scan(
-                    accum_body,
-                    (s, telem_zero(cfg, statics)),
-                    None, length=telemetry_every)
-                return s, _telem_finalize(acc)
-
-            def go(state):
-                return jax.lax.scan(window, state, None,
-                                    length=n_steps // telemetry_every)
-
-    if check_on:
-        from jax.experimental import checkify
-
-        err, out = checkify.checkify(go)(state)
-        err.throw()
-        return out
-    return go(state)
+    return _checkified(check, go, state)
 
 
 def run_segment(
@@ -1495,78 +1507,25 @@ def run_segment(
     """Advance ``n_ticks`` carrying a RAW ``TelemetrySummary`` accumulator.
 
     This is ``run_episode(summary_only=True)`` (or ``macro=True``) cut at
-    an arbitrary tick boundary: the scan/while bodies are the exact same
-    compiled programs, but the accumulator enters un-zeroed and leaves
-    un-finalized, so a sequence of segments threaded through
-    ``(state, acc)`` reproduces the single-call episode bit-for-bit —
-    the host-level primitive snapshot/resume (checkpoint.episode) is
-    built on. Seed ``acc`` with ``telem_zero(cfg, statics)`` and apply
-    ``_telem_finalize`` once after the last segment. Segment edges clamp
-    the macro fast-forward exactly like ``telemetry_every`` window edges,
-    so job/queue state and the PRNG stream stay bit-identical to the
-    uninterrupted run (the skip-accounting diagnostics
-    ``n_steps``/``macro_steps`` count the forced boundary breakpoints,
-    same as windowed telemetry).
+    an arbitrary tick boundary: both run the same loop, but the
+    accumulator enters un-zeroed and leaves un-finalized, so a sequence
+    of segments threaded through ``(state, acc)`` reproduces the
+    single-call episode bit-for-bit — the host-level primitive
+    snapshot/resume (checkpoint.episode) is built on. Seed ``acc`` with
+    ``telem_zero(cfg, statics)`` and apply ``telem_finalize`` once after
+    the last segment. Segment edges clamp the macro fast-forward exactly
+    like ``telemetry_every`` window edges, so job/queue state and the
+    PRNG stream stay bit-identical to the uninterrupted run (the
+    skip-accounting diagnostics ``n_steps``/``macro_steps`` count the
+    forced boundary breakpoints, same as windowed telemetry).
 
     The ``REPRO_CHECKIFY=1`` invariant harness instruments eager calls
     per committed step, exactly as in ``run_episode``.
     """
-    from repro.utils import invariants
-
-    check_on = invariants.enabled() and not isinstance(
-        state.t, jax.core.Tracer)
-
-    if macro:
-        mstep = make_macro_step(cfg, statics, scheduler, **kw)
-        if check_on:
-            raw_mstep = mstep
-
-            def mstep(s, a, n):
-                s, a, took = raw_mstep(s, a, n)
-                invariants.check_state(cfg, statics, s)
-                return s, a, took
-
-        def go(state, acc):
-            def wcond(c):
-                return c[2] < n_ticks
-
-            def wbody(c):
-                s, a, ticks = c
-                s, a, took = mstep(s, a, n_ticks - ticks)
-                return (s, a, ticks + took)
-
-            s, a, _ = jax.lax.while_loop(
-                wcond, wbody, (state, acc, jnp.int32(0)))
-            return s, a
-    else:
-        step = make_step(cfg, statics, scheduler, **kw)
-        if check_on:
-            raw_step = step
-
-            def step(s, a):
-                s, out = raw_step(s, a)
-                invariants.check_state(cfg, statics, s)
-                return s, out
-
-        def accum_body(carry, _):
-            s, acc = carry
-            s, out = step(s, jnp.int32(-1))
-            return (s, _telem_update(
-                acc, out, resilience_on=cfg.resilience_on,
-                serving_on=cfg.serving_on)), None
-
-        def go(state, acc):
-            (fs, acc), _ = jax.lax.scan(
-                accum_body, (state, acc), None, length=n_ticks)
-            return fs, acc
-
-    if check_on:
-        from jax.experimental import checkify
-
-        err, out = checkify.checkify(go)(state, acc)
-        err.throw()
-        return out
-    return go(state, acc)
+    check = _eager_checks(state)
+    advance = _advancer(cfg, statics, scheduler, macro, check, kw)
+    return _checkified(check, lambda s, a: advance(s, a, n_ticks), state,
+                       acc)
 
 
 def summary_columns(state: SimState,

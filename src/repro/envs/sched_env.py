@@ -56,7 +56,7 @@ from repro.core import faults as flt
 from repro.core import placement as plc
 from repro.core import schedulers as sched
 from repro.core import thermal
-from repro.core.sim import make_macro_step, make_step
+from repro.core.sim import _macro_advance, make_macro_step, make_step
 from repro.data.bank import stack_workloads
 from repro.scenarios import Scenario, eval_signal, power_cap_at
 from repro.core.state import (
@@ -288,13 +288,7 @@ class SchedEnv:
         if self.macro and n_idle > 0:
             # one macro advance clamped to the agent-decision boundary:
             # full steps only on event ticks, quiet ticks fast-forwarded
-            def idle(c):
-                s, a, ticks = c
-                s, a, took = self._macro_idle(s, a, n_idle - ticks)
-                return (s, a, ticks + took)
-
-            sim, acc, _ = jax.lax.while_loop(
-                lambda c: c[2] < n_idle, idle, (sim, acc, jnp.int32(0)))
+            sim, acc = _macro_advance(self._macro_idle, sim, acc, n_idle)
         else:
             def sub(carry, _):
                 s, a = carry

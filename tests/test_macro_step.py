@@ -7,10 +7,10 @@ the per-tick episode with quiet ticks fast-forwarded —
   stream) is EXACT: on dense-scatter-budget configs every accumulator is
   bit-identical too, because fast ticks run the same compiled power chain
   and the same accounting tail;
-- on large configs (chunked count-matrix power path) and for telemetry
-  reductions whose fusion context differs between the two compiled
-  programs (net_load's cross-job sum), energy/cost/carbon accounting is
-  pinned within float-accumulation tolerance instead;
+- on large configs (chunked count-matrix power path) energy/cost/carbon
+  accounting, and the telemetry mean of net_load (whose per-tick add the
+  compiler may contract into an FMA in one program and not the other),
+  are pinned within float-accumulation tolerance instead;
 - the predicted ``quiet_horizon`` never overshoots the next event.
 """
 
@@ -35,7 +35,10 @@ from repro.core import (
     quiet_horizon,
     run_episode,
     run_fleet,
+    run_segment,
     summary,
+    telem_finalize,
+    telem_zero,
 )
 from repro.core.placement import PLACEMENTS, make_policy
 from repro.core.schedulers import SCHEDULERS, queued_mask
@@ -77,9 +80,16 @@ def _assert_equiv(fs, tel, fs2, tel2, *, exact_accum=True):
         if getattr(tel, f) is None:   # streamed-admission counters: absent
             assert getattr(tel2, f) is None, f   # on the resident path
             continue
+        # mean_net_load on the non-shared path: the per-tick program folds
+        # net_load's division by the bisection bandwidth (a multiply by
+        # its reciprocal) into the accumulator's add as one FMA, the macro
+        # program does not, so each tick's sum may round apart by an ulp;
+        # over the TX-GAIA slice's 600 ticks the means part by 2.5e-6.
+        # It takes the accumulators' tolerance of that path.
+        rtol = 1e-5 if not exact_accum and f == "mean_net_load" else 1e-6
         np.testing.assert_allclose(
             np.asarray(getattr(tel, f)), np.asarray(getattr(tel2, f)),
-            rtol=1e-6, atol=1e-9,
+            rtol=rtol, atol=1e-9,
             err_msg=f"telemetry {f} beyond float tolerance")
 
 
@@ -175,17 +185,32 @@ def test_macro_policy_grid_equivalence():
                 raise AssertionError(f"policy ({sel}, {pl}): {e}") from e
 
 
-def test_macro_telemetry_windows_tick_aligned():
+@pytest.mark.parametrize("reference", ["per_tick", "segments"])
+def test_macro_telemetry_windows_tick_aligned(reference):
     """telemetry_every windows clamp the horizon, so windowed summaries
-    match the per-tick ones window by window."""
+    match the per-tick ones window by window; and a window is one
+    ``run_segment`` from a zeroed accumulator, finalized, so chained
+    segments reproduce the windows bit for bit, skip accounting
+    included."""
     cfg = tiny_cluster()
     jobs, bank = synth_workload(cfg, 24, 900.0, seed=4)
     statics = build_statics(cfg, bank)
     state = load_jobs(init_state(cfg, statics, jax.random.key(0)), jobs)
-    fs, wins = jax.jit(lambda s: run_episode(
-        cfg, statics, s, 900, "fcfs", telemetry_every=90))(state)
     fs2, wins2 = jax.jit(lambda s: run_episode(
         cfg, statics, s, 900, "fcfs", telemetry_every=90, macro=True))(state)
+    if reference == "segments":
+        seg = jax.jit(lambda s: run_segment(
+            cfg, statics, s, telem_zero(cfg, statics), 90, "fcfs",
+            macro=True))
+        fs, accs = state, []
+        for _ in range(10):
+            fs, acc = seg(fs)
+            accs.append(telem_finalize(acc))
+        wins = jax.tree.map(lambda *w: jnp.stack(w), *accs)
+        _assert_tree_bitwise((fs, wins), (fs2, wins2))
+        return
+    fs, wins = jax.jit(lambda s: run_episode(
+        cfg, statics, s, 900, "fcfs", telemetry_every=90))(state)
     assert np.shape(wins2.n_steps) == (10,)
     np.testing.assert_array_equal(np.asarray(wins2.n_steps),
                                   np.full(10, 90.0))
@@ -203,6 +228,15 @@ def test_macro_telemetry_windows_tick_aligned():
         if f == "key":
             a, b = jax.random.key_data(a), jax.random.key_data(b)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _assert_tree_bitwise(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        if jnp.issubdtype(x.dtype, jax.dtypes.prng_key):
+            x, y = jax.random.key_data(x), jax.random.key_data(y)
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_summary_accepts_windowed_telemetry():

@@ -23,7 +23,7 @@ from repro.core import (
 )
 from repro.core import schedulers as sched
 from repro.core.placement import make_policy
-from repro.core.sim import _telem_finalize
+from repro.core.sim import telem_finalize
 from repro.data import synth_workload, write_supercloud_csvs, load_supercloud
 from repro.utils.errors import ConfigError
 
@@ -247,7 +247,7 @@ def test_segments_match_one_episode():
     s, a = s0, telem_zero(cfg, statics)
     for _ in range(5):
         s, a = seg(statics, s, a)
-    a = _telem_finalize(a)
+    a = telem_finalize(a)
     _records_equal(fs, s, N)
     for k in INTEGRALS:
         assert float(getattr(fs, k)) == float(getattr(s, k)), k

@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.sim import tx_gaia
 from repro.core import build_statics, init_state, make_macro_step, make_step
 from repro.core.placement import make_policy
-from repro.core.sim import _telem_zero
+from repro.core.sim import telem_zero
 from repro.kernels.node_power import node_power_pallas, power_scatter_pallas
 from repro.kernels.rack_thermal import rack_thermal_pallas
 from repro.utils.hlo import tpu_kernel_calls
@@ -233,7 +233,7 @@ def test_tx_gaia_macro_step_compiles(one_chip):
     cfg = tx_gaia()
     statics = build_statics(cfg)
     state = init_state(cfg, statics, jax.random.key(0))
-    acc = _telem_zero(cfg.resilience_on, cfg.serving_on)
+    acc = telem_zero(cfg, statics)
 
     def macro(st, s, a):
         return make_macro_step(cfg, st, "fcfs")(s, a, 3600)
