@@ -12,6 +12,7 @@ here on CPU.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Tuple
 
 import jax
@@ -34,32 +35,113 @@ class PowerOut(NamedTuple):
     gflops: jax.Array         # utilization-weighted delivered GFLOP/s
 
 
+def _quantum_index(cfg: SimConfig, t, start_t, q: int):
+    """Index of the telemetry quantum each job is in at time ``t``: its
+    age since start, in whole quanta, clipped to the bank's ``q``."""
+    age = jnp.maximum(t - start_t, 0.0)
+    return jnp.clip((age / cfg.trace_quanta).astype(jnp.int32), 0, q - 1)
+
+
+def _bank_rows(state: SimState, statics: Statics):
+    """Where each job's row of the telemetry bank lies, in the bank's
+    layout: (the row's leading indices, from which a window is sliced;
+    ``point(bank, qi)``, which reads quantum ``qi`` of each row by a
+    gather of one index per job). The rows are the slots (one workload),
+    the workload id and the slots (banked), or the slots' trace ids
+    (streamed). The point reads index with the scalar workload id
+    (banked) and ``take_along_axis`` (one workload): spelled through the
+    window's leading indices, the same gather compiles to another
+    program."""
+    j = jnp.arange(state.jstate.shape[0])
+    if statics.trace is not None:
+        rows = jnp.maximum(state.stream.tid, 0)
+        return (rows,), lambda bank, qi: bank[rows, qi]
+    if statics.cpu_trace.ndim == 3:
+        return ((jnp.broadcast_to(state.workload, j.shape), j),
+                lambda bank, qi: bank[state.workload, j, qi])
+    return (j,), lambda bank, qi: jnp.take_along_axis(
+        bank, qi[:, None], axis=1)[:, 0]
+
+
 def job_utilization(cfg: SimConfig, state: SimState, statics: Statics):
     """Per-job cpu/gpu utilization at current sim time from the telemetry
     bank (quanta-averaged, as RAPS replays traces).
 
+    One J-element gather per resource, one index per job: the event tick
+    reads its utilization this way, and so does every tick of the shared
+    (per-tick) fast path. A fast-tick chunk of the count-matrix path reads
+    a window of quanta per job instead (``job_utilization_chunk``).
+
     With a banked (W, J, Q) trace (see ``Statics``), the lookup gathers
-    through the traced ``state.workload`` id — one J-element gather per
-    step, identical cost to the unbatched path, and the bank itself is
-    never copied per env (the lightweight-state rollout engine's key
-    invariant). A streamed trace's bank has a row per trace job, read
-    through each slot's trace id."""
+    through the traced ``state.workload`` id, at the cost of the
+    unbatched path, and the bank itself is never copied per env (the
+    lightweight-state rollout engine's key invariant). A streamed trace's
+    bank has a row per trace job, read through each slot's trace id."""
     running = (state.jstate == RUNNING).astype(jnp.float32)
-    age = jnp.maximum(state.t - state.start_t, 0.0)
+    qi = _quantum_index(cfg, state.t, state.start_t,
+                        statics.cpu_trace.shape[-1])
+    point = _bank_rows(state, statics)[1]
+    return (point(statics.cpu_trace, qi) * running,
+            point(statics.gpu_trace, qi) * running)
+
+
+def _per_tick_utilization(cfg: SimConfig, state: SimState,
+                          statics: Statics, ts: jax.Array):
+    """(C, J) utilization at each tick time of ``ts``, gathered tick by
+    tick: one index per job and tick."""
+    return jax.vmap(lambda t: job_utilization(
+        cfg, state._replace(t=t), statics))(ts)
+
+
+def chunk_window(cfg: SimConfig, n_ticks: int, q: int) -> int:
+    """Quanta a job can meet in ``n_ticks`` consecutive ticks: their times
+    span (n_ticks - 1) * dt, and one tick more of slack keeps the count
+    safe against float32 rounding of the tick times; never more than the
+    bank's ``q``. TX-GAIA (16 ticks of 1 s, 10 s quanta): 3."""
+    return min(q, math.floor(n_ticks * cfg.dt / cfg.trace_quanta) + 2)
+
+
+def job_utilization_chunk(cfg: SimConfig, state: SimState,
+                          statics: Statics, ts: jax.Array):
+    """(C, J) cpu/gpu utilization at each of C increasing tick times ``ts``
+    (``dt`` apart) of a frozen job table: bit for bit
+    ``vmap(job_utilization)`` over ``ts``.
+
+    Over C ticks a job's quantum index moves through at most
+    ``M = chunk_window(cfg, C, Q)`` quanta, so each job's telemetry is
+    read as ONE window of M quanta of its bank row, starting at its first
+    tick's quantum (a gather whose slice is (1, M); clamped to the row's
+    end as ``lax.dynamic_slice`` does), and each tick selects its element
+    of the window. The per-tick form gathers C elements a job, one index
+    each, and the TPU runs such a gather element by element: at TX-GAIA
+    (C = 16, M = 3) the window reads 3 of them. Where M >= C the window
+    saves nothing, and the chunk keeps the per-tick gather."""
+    C = ts.shape[0]
     q = statics.cpu_trace.shape[-1]
-    qi = jnp.clip((age / cfg.trace_quanta).astype(jnp.int32), 0, q - 1)
-    if statics.trace is not None:
-        rows = jnp.maximum(state.stream.tid, 0)
-        cpu = statics.cpu_trace[rows, qi]
-        gpu = statics.gpu_trace[rows, qi]
-    elif statics.cpu_trace.ndim == 3:
-        j = jnp.arange(state.jstate.shape[0])
-        cpu = statics.cpu_trace[state.workload, j, qi]
-        gpu = statics.gpu_trace[state.workload, j, qi]
-    else:
-        cpu = jnp.take_along_axis(statics.cpu_trace, qi[:, None], axis=1)[:, 0]
-        gpu = jnp.take_along_axis(statics.gpu_trace, qi[:, None], axis=1)[:, 0]
-    return cpu * running, gpu * running
+    M = chunk_window(cfg, C, q)
+    if M >= C:
+        return _per_tick_utilization(cfg, state, statics, ts)
+    running = (state.jstate == RUNNING).astype(jnp.float32)
+    qi = _quantum_index(cfg, ts[:, None], state.start_t[None, :], q)
+    # qi is monotone in t and clipped at q - 1, so qi - start lies in
+    # [0, M - 1] whether or not the window start is clamped
+    start = jnp.minimum(qi[0], q - M)
+    off = qi - start                                              # (C, J)
+    lead = _bank_rows(state, statics)[0]
+    ones = (1,) * len(lead)
+
+    def window(bank):                                             # (J, M)
+        return jax.vmap(lambda *ix: jax.lax.dynamic_slice(
+            bank, ix, ones + (M,)).reshape(M))(*lead, start)
+
+    def per_tick(w):                                              # (C, J)
+        u = jnp.broadcast_to(w[:, 0], off.shape)
+        for m in range(1, M):
+            u = jnp.where(off >= m, w[:, m], u)
+        return u * running
+
+    return (per_tick(window(statics.cpu_trace)),
+            per_tick(window(statics.gpu_trace)))
 
 
 # Job->node reductions (release into ``free``, per-node loads, the macro

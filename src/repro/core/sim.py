@@ -50,7 +50,7 @@ from repro.core.placement import Policy
 from repro.core.power import (
     PowerOut,
     compute_power,
-    job_utilization,
+    job_utilization_chunk,
     node_counts,
     power_from_fracs,
     use_dense_scatter,
@@ -1139,16 +1139,17 @@ def make_macro_step(
     def power_chunk(s: SimState, cnt):
         """(ts, PowerOut-with-leading-C-axis) for the next C ticks under a
         frozen machine state: utilization only drifts through the
-        trace-quanta index, so per-node loads for the whole chunk are ONE
-        gemm against the per-segment job->node count matrix instead of C
-        scatters — the arithmetic-intensity trick that makes fast ticks
-        ~O(scalar). The chain itself is the shared ``power_from_fracs``
-        (vmapped over the chunk), so the rectifier/COP model has a single
-        source of truth."""
+        trace-quanta index, so the chunk reads each job's telemetry as one
+        window of the few quanta its C ticks can meet
+        (``job_utilization_chunk``; the per-tick gather where the window
+        would be as long as the chunk), and per-node loads for the whole
+        chunk are ONE gemm against the per-segment job->node count matrix
+        instead of C scatters — the arithmetic-intensity trick that makes
+        fast ticks ~O(scalar). The chain itself is the shared
+        ``power_from_fracs`` (vmapped over the chunk), so the
+        rectifier/COP model has a single source of truth."""
         ts = s.t + cfg.dt * jnp.arange(1, C + 1, dtype=jnp.float32)
-        cpu_u, gpu_u = jax.vmap(
-            lambda t: job_utilization(cfg, s._replace(t=t), statics)
-        )(ts)                                                      # (C, J)
+        cpu_u, gpu_u = job_utilization_chunk(cfg, s, statics, ts)  # (C, J)
         loads = jnp.matmul(
             jnp.concatenate([cpu_u * s.req[0][None, :],
                              gpu_u * s.req[1][None, :]]),

@@ -20,10 +20,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.sim import tx_gaia
+from repro.configs.sim import tiny_cluster, tx_gaia
 from repro.core import build_statics, init_state, make_macro_step, make_step
+from repro.core import power, sim
 from repro.core.placement import make_policy
-from repro.core.sim import telem_zero
+from repro.core.sim import CHUNK_TICKS, telem_zero
 from repro.kernels.node_power import node_power_pallas, power_scatter_pallas
 from repro.kernels.rack_thermal import rack_thermal_pallas
 from repro.utils.hlo import tpu_kernel_calls
@@ -243,3 +244,91 @@ def test_tx_gaia_macro_step_compiles(one_chip):
     # release, loads and the count matrix: no slot scatter or sort
     assert slot_reductions(c.as_text(), SLOTS) == []
     assert "tick.node_counts" in c.as_text()
+
+
+def _macro_program(cfg, sharding):
+    """(lowered, compiled) TX-GAIA-style macro step of ``cfg`` for
+    ``sharding``'s device."""
+    statics = build_statics(cfg)
+    state = init_state(cfg, statics, jax.random.key(0))
+    acc = telem_zero(cfg, statics)
+
+    def macro(st, s, a):
+        return make_macro_step(cfg, st, "fcfs")(s, a, 3600)
+
+    lowered = jax.jit(macro).lower(*(_sds(a, sharding)
+                                     for a in (statics, state, acc)))
+    return lowered, lowered.compile()
+
+
+STACK_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def _program(text):
+    """Optimised HLO less metadata and the stack-frame tables: what the
+    chip runs, whatever source lines it came from."""
+    out, table = [], False
+    for line in text.splitlines():
+        if line in STACK_TABLES or table:
+            table = line != ""
+            continue
+        out.append(re.sub(r", metadata=\{[^}]*\}", "", line))
+    return "\n".join(out)
+
+
+def _stage_gathers(text, stage):
+    """Elements of each gather of the optimised HLO ``text`` named under
+    ``stage``."""
+    return [_elems(shape) for name, shape, opcode, _ in _instructions(text)
+            if opcode == "gather" and re.search(
+                rf"{name} = .*op_name=\"[^\"]*{re.escape(stage)}/", text)]
+
+
+def test_tx_gaia_fast_ticks_read_telemetry_by_window(one_chip):
+    """The TX-GAIA macro step's fast-tick chunk reads each job's telemetry
+    as one (1, M) window of its bank row (M = 3 for 16 ticks of 1 s and
+    10 s quanta), not CHUNK_TICKS x max_jobs element gathers. The TPU
+    compiler splits each window gather into M gathers of max_jobs
+    elements, so the chunk reads at most 2 x M x max_jobs elements."""
+    cfg = tx_gaia()
+    J = cfg.max_jobs
+    M = power.chunk_window(cfg, CHUNK_TICKS, build_statics(cfg).cpu_trace
+                           .shape[-1])
+    assert M == 3
+    lowered, compiled = _macro_program(cfg, one_chip)
+    windows = re.findall(rf"= f32\[{J},1,{M}\][^ ]* gather\(.*"
+                         rf"slice_sizes=\{{1,{M}\}}",
+                         lowered.as_text(dialect="hlo"))
+    assert len(windows) == 2                   # cpu and gpu utilization
+    text = compiled.as_text()
+    assert large_gathers(text, CHUNK_TICKS * J) == []
+    chunk = _stage_gathers(text, "macro.power_chunk")
+    assert chunk and sum(chunk) <= 2 * M * J
+
+
+def test_tx_gaia_fast_ticks_per_tick_gathers_are_found(one_chip,
+                                                       monkeypatch):
+    """With the tick-by-tick gather put back, the same program holds
+    gathers of CHUNK_TICKS x max_jobs elements, which ``large_gathers``
+    finds."""
+    monkeypatch.setattr(sim, "job_utilization_chunk",
+                        power._per_tick_utilization)
+    cfg = tx_gaia()
+    _, compiled = _macro_program(cfg, one_chip)
+    assert len(large_gathers(compiled.as_text(),
+                             CHUNK_TICKS * cfg.max_jobs)) == 2
+
+
+def test_shared_path_program_is_the_same_either_way(one_chip, monkeypatch):
+    """A test-size macro step takes the shared per-tick power path, which
+    reads no chunk: its optimised program is the same whichever form
+    reads a chunk's telemetry."""
+    cfg = tiny_cluster()
+    assert power.use_dense_scatter(cfg.max_jobs * cfg.max_nodes_per_job,
+                                   cfg.n_nodes)
+    window = _macro_program(cfg, one_chip)[1].as_text()
+    assert "macro.power_chunk" not in window
+    monkeypatch.setattr(sim, "job_utilization_chunk",
+                        power._per_tick_utilization)
+    per_tick = _macro_program(cfg, one_chip)[1].as_text()
+    assert _program(window) == _program(per_tick)
